@@ -246,7 +246,6 @@ def standard_audit(n_workers: int = 4, tau: int = 2,
     from repro.core import (DSMConfig, constant, dsm_init, get_base_optimizer,
                             make_dsm_step, make_local_phase)
     from repro.data.pipeline import MarkovCorpus, dsm_batches
-    from repro.distributed.compat import shard_map
     from repro.launch.mesh import host_training_mesh
     from repro.models import transformer as T
 
@@ -313,11 +312,11 @@ def standard_audit(n_workers: int = 4, tau: int = 2,
                          global_sharded=False)
 
         def psum_workers(tree):
-            return shard_map(
+            return jax.shard_map(
                 lambda t: jax.tree.map(
                     lambda x: jax.lax.psum(x, "worker"), t),
                 mesh=mesh, in_specs=P("worker"), out_specs=P(),
-                check_rep=False)(tree)
+                check_vma=False)(tree)
 
         def planted(state, batch):
             new_state, metrics = step(state, batch)

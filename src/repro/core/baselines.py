@@ -213,8 +213,10 @@ def global_adamw(
     """Alg. 7: AdamW on the pseudo-gradient g = (x0 - x_tau)/gamma."""
 
     def init_aux(params):
-        z = jax.tree.map(lambda p: jnp.zeros_like(p, jnp.float32), params)
-        return _GlobalAdamWAux(m=z, v=z)
+        def zeros():
+            return jax.tree.map(lambda p: jnp.zeros_like(p, jnp.float32), params)
+
+        return _GlobalAdamWAux(m=zeros(), v=zeros())
 
     def global_update(x0, aux, x_tau, gamma, t):
         g = jax.tree.map(lambda a, b: (_f32(a) - _f32(b)) / gamma, x0, x_tau)
@@ -301,7 +303,7 @@ def make_mv_signsgd_step(
     def init(params, n_workers):
         return MVState(
             x=params,
-            x_prev=params,
+            x_prev=jax.tree.map(jnp.copy, params),  # own buffers: state is donated
             m=_broadcast_workers(
                 jax.tree.map(lambda p: jnp.zeros_like(p, jnp.float32), params), n_workers
             ),

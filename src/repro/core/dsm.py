@@ -346,16 +346,14 @@ def make_local_phase(
 
     from jax.sharding import PartitionSpec as P
 
-    from repro.distributed.compat import shard_map
-
     wspec = P("worker")
     n_worker_devices = dict(zip(mesh.axis_names, mesh.devices.shape))["worker"]
-    sharded_block = shard_map(
+    sharded_block = jax.shard_map(
         local_phase_block,
         mesh=mesh,
         in_specs=(wspec, wspec, wspec, P(), P()),
         out_specs=(wspec, wspec, P(None, "worker")),
-        check_rep=False,
+        check_vma=False,
     )
 
     def local_phase(params_w, base_state_w, batch, gamma, inner0):

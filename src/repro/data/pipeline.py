@@ -3,9 +3,10 @@
 The paper pre-trains on OpenWebText; offline we provide two corpora with
 real sequential structure (so optimizers separate, unlike iid noise):
 
-  * ``MarkovCorpus`` — an order-2 token-level Markov chain with a sparse
-    random transition kernel.  Entropy is controlled, loss floors are
-    computable, and 100-step training curves already separate optimizers.
+  * ``MarkovCorpus`` — an order-2 token-level Markov chain whose sparse
+    transition kernel is a hash of the seed, so it costs no memory per
+    context at any vocabulary size.  Entropy is controlled, and 100-step
+    training curves already separate optimizers.
   * ``TextCorpus``   — byte-level corpus from any file (self-hosting: we
     ship our own source tree as the default corpus).
 
@@ -23,27 +24,52 @@ from typing import Iterator
 import numpy as np
 
 
+_GOLDEN = np.uint64(0x9E3779B97F4A7C15)
+_CDF_BANK = 4096  # Dirichlet CDFs that the contexts' hashes choose among
+
+
+def _mix64(x: np.ndarray) -> np.ndarray:
+    """splitmix64 finalizer: a fixed bijective hash of uint64 arrays."""
+    x = x + _GOLDEN
+    x = (x ^ (x >> np.uint64(30))) * np.uint64(0xBF58476D1CE4E5B9)
+    x = (x ^ (x >> np.uint64(27))) * np.uint64(0x94D049BB133111EB)
+    return x ^ (x >> np.uint64(31))
+
+
 class MarkovCorpus:
-    """Order-2 Markov chain over ``vocab`` tokens with ``branch`` choices."""
+    """Order-2 Markov chain over ``vocab`` tokens with ``branch`` choices.
+
+    The transition of context ``(a, b)`` is computed, not stored: a hash of
+    ``(seed, a, b)`` picks one of a fixed bank of Dirichlet(0.5) CDFs over
+    the ``branch`` choices, and a hash of ``(seed, a, b, choice)`` names the
+    next token.  Memory is O(bank * branch) at any vocabulary size (GPT-2's
+    50,257 included), where a dense table would be O(vocab^2 * branch).
+    """
 
     def __init__(self, vocab: int, branch: int = 8, seed: int = 0):
         self.vocab = vocab
         rng = np.random.default_rng(seed)
-        # transition table: (vocab, vocab) -> `branch` next tokens + probs
-        self.next_tokens = rng.integers(0, vocab, size=(vocab, vocab, branch))
-        p = rng.dirichlet(np.ones(branch) * 0.5, size=(vocab, vocab))
-        self.next_cdf = np.cumsum(p, axis=-1)
+        self._salt = np.uint64(rng.integers(0, 2**63))
+        cdf = np.cumsum(rng.dirichlet(np.ones(branch) * 0.5, size=_CDF_BANK),
+                        axis=-1)
+        cdf[:, -1] = 1.0  # rounding must never push u past the last choice
+        self._cdf = cdf
+
+    def transition(self, a: np.ndarray, b: np.ndarray, u: np.ndarray) -> np.ndarray:
+        """Next tokens after contexts ``(a, b)`` for uniforms ``u`` in [0, 1)."""
+        ctx = _mix64((a.astype(np.uint64) * np.uint64(self.vocab)
+                      + b.astype(np.uint64)) ^ self._salt)
+        cdf = self._cdf[(ctx % np.uint64(len(self._cdf))).astype(np.int64)]
+        choice = (u[:, None] > cdf).sum(axis=-1).astype(np.uint64)
+        nxt = _mix64(ctx + (choice + np.uint64(1)) * _GOLDEN)
+        return (nxt % np.uint64(self.vocab)).astype(np.int32)
 
     def sample(self, rng: np.random.Generator, batch: int, seq: int) -> np.ndarray:
         out = np.empty((batch, seq), dtype=np.int32)
-        out[:, 0] = rng.integers(0, self.vocab, size=batch)
-        out[:, 1] = rng.integers(0, self.vocab, size=batch)
+        out[:, :2] = rng.integers(0, self.vocab, size=(batch, 2))
         u = rng.random(size=(batch, seq))
         for t in range(2, seq):
-            a, b = out[:, t - 2], out[:, t - 1]
-            cdf = self.next_cdf[a, b]                       # (batch, branch)
-            idx = (u[:, t : t + 1] > cdf).sum(axis=-1)
-            out[:, t] = self.next_tokens[a, b, idx]
+            out[:, t] = self.transition(out[:, t - 2], out[:, t - 1], u[:, t])
         return out
 
 

@@ -54,8 +54,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.distributed.compat import shard_map
-
 from repro.distributed.sharding import param_pspecs
 from repro.kernels.dsm_update import LANES, dsm_update_2d
 
@@ -236,11 +234,11 @@ def _sharded_step_kernel(x0, m, x_tau, gamma, cfg, mesh,
         ]
         return [o[0] for o in outs], [o[1] for o in outs]
 
-    new_x_slabs, new_m_slabs = shard_map(
+    new_x_slabs, new_m_slabs = jax.shard_map(
         rank_fn, mesh=mesh,
         in_specs=(P(), slab_spec, slab_spec, slab_spec),
         out_specs=(slab_spec, slab_spec),
-        check_rep=False,
+        check_vma=False,
     )(gamma32, x0_slabs, m_slabs, xt_slabs)
 
     new_x0 = jax.tree.unflatten(
@@ -336,9 +334,9 @@ def sharded_stat_sums(x0: PyTree, m: PyTree, x_tau: PyTree, gamma,
         return jax.lax.psum(tot, GLOBAL_AXES)
 
     leaf_specs = list(spec_leaves)
-    return shard_map(
+    return jax.shard_map(
         rank_fn, mesh=mesh,
         in_specs=(P(), leaf_specs, leaf_specs, leaf_specs),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )(jnp.asarray(gamma, jnp.float32), x0_leaves, m_leaves, xt_leaves)

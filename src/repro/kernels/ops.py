@@ -1,7 +1,7 @@
 """jit'd wrappers: pytree <-> lane-aligned 2D slabs for the Pallas kernels.
 
-``interpret`` defaults to True off-TPU (this container is CPU-only; the
-kernels TARGET TPU and are validated in interpret mode).
+``interpret`` defaults to True off-TPU: the kernels target the TPU, and on
+the CPU (tests) they run in Pallas interpret mode.
 """
 
 from __future__ import annotations

@@ -1,11 +1,14 @@
 """Training launcher.
 
 Two modes:
-  * local (default)   — really trains on the available devices (CPU here):
-      PYTHONPATH=src python -m repro.launch.train --arch gpt2_small_smoke \\
-          --algorithm dsm --tau 12 --steps 100
-    ``--arch`` accepts ``<id>`` (FULL config — only sensible on a real
-    cluster), ``<id>_smoke`` (reduced family variant), or ``nano``.
+  * local (default)   — trains on the devices JAX finds (TPU chips, or the
+    CPU under ``JAX_PLATFORMS=cpu``):
+      PYTHONPATH=src python -m repro.launch.train --arch gpt2_small \\
+          --algorithm dsm --tau 12 --seq 1024 --steps 100
+    ``--arch`` accepts ``<id>`` (FULL config at published widths; GPT-2
+    small fits one TPU v5e chip at W=4, b_micro=4, seq=1024),
+    ``<id>_smoke`` (reduced family variant), or ``nano``.  Rematerialization
+    follows the arch's ``TopologyConfig.remat``.
   * plan              — prints the production launch plan for the 16x16 /
     2x16x16 mesh (worker count, shardings, per-chip memory from the
     dry-run artifact) without touching devices:
@@ -17,9 +20,33 @@ from __future__ import annotations
 import argparse
 import json
 import os
+from typing import Callable, Optional
 
 from repro.configs import load_arch
 from repro.configs.base import ModelConfig
+
+# fixed, so the cache key's path is the same on every run of this checkout
+CACHE_DIR = os.path.normpath(os.path.join(
+    os.path.dirname(os.path.abspath(__file__)), os.pardir, os.pardir, os.pardir,
+    ".jax_cache"))
+
+
+def compile_cache_dir() -> Optional[str]:
+    """The persistent compile cache this process should set, or None when
+    ``JAX_COMPILATION_CACHE_DIR`` is set and JAX picks it up itself."""
+    if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    return CACHE_DIR
+
+
+def enable_compile_cache() -> Optional[str]:
+    """Turn on JAX's persistent compile cache; call once at start-up."""
+    import jax
+
+    path = compile_cache_dir()
+    if path is not None:
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def _resolve_arch(name: str) -> tuple[ModelConfig, object]:
@@ -36,7 +63,7 @@ def _resolve_arch(name: str) -> tuple[ModelConfig, object]:
     return mod.FULL, mod.TOPO
 
 
-def main():
+def build_parser() -> argparse.ArgumentParser:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="nano")
     ap.add_argument("--algorithm", default="dsm",
@@ -100,12 +127,49 @@ def main():
                     help="run the loop under jax_debug_nans (chaos tier: "
                          "masked NaNs must never reach a jit output)")
     ap.add_argument("--plan", action="store_true")
-    args = ap.parse_args()
+    return ap
+
+
+def train(args: argparse.Namespace, log: Optional[Callable] = print):
+    """Build the run that the launcher's arguments describe and train it.
+
+    Returns ``(cfg, settings, corpus, result)``; ``result`` is
+    ``run_training``'s dict.
+    """
+    from repro.data.pipeline import MarkovCorpus
+    from repro.train.trainer import TrainSettings, run_training
 
     cfg, topo = _resolve_arch(args.arch)
-    tau = args.tau or topo.tau
+    s = TrainSettings(
+        algorithm=args.algorithm, base_opt=args.base_opt or topo.base_opt,
+        n_workers=args.n_workers, tau=args.tau or topo.tau, steps=args.steps,
+        seq=args.seq, b_micro=args.b_micro, peak_lr=args.peak_lr,
+        global_lr=args.global_lr, remat=topo.remat,
+        eval_every=max(args.steps // 5, 1),
+        use_kernel=args.use_kernel, zero_sharded=args.zero_sharded,
+        device_parallel_local=args.device_parallel_local,
+        faults=args.faults,
+        guard_nonfinite=args.guard_nonfinite,
+        guard_spike_factor=args.guard_spike_factor,
+        checkpoint_dir=args.checkpoint_dir,
+        checkpoint_every=args.checkpoint_every,
+        resume=args.resume,
+        sanitize=args.sanitize,
+        sanitize_nans=args.sanitize_nans,
+        run_dir=args.run_dir,
+        log_every=args.log_every,
+        profile_steps=args.profile_steps,
+    )
+    corpus = MarkovCorpus(cfg.vocab_size, seed=1)
+    return cfg, s, corpus, run_training(cfg, s, corpus, log=log)
+
+
+def main(argv=None):
+    args = build_parser().parse_args(argv)
 
     if args.plan:
+        cfg, topo = _resolve_arch(args.arch)
+        tau = args.tau or topo.tau
         from repro.configs import specs as S
 
         n = S.param_count(cfg)
@@ -131,30 +195,8 @@ def main():
         print(json.dumps(plan, indent=2))
         return
 
-    from repro.data.pipeline import MarkovCorpus
-    from repro.train.trainer import TrainSettings, run_training
-
-    s = TrainSettings(
-        algorithm=args.algorithm, base_opt=args.base_opt or topo.base_opt,
-        n_workers=args.n_workers, tau=tau, steps=args.steps, seq=args.seq,
-        b_micro=args.b_micro, peak_lr=args.peak_lr, global_lr=args.global_lr,
-        eval_every=max(args.steps // 5, 1),
-        use_kernel=args.use_kernel, zero_sharded=args.zero_sharded,
-        device_parallel_local=args.device_parallel_local,
-        faults=args.faults,
-        guard_nonfinite=args.guard_nonfinite,
-        guard_spike_factor=args.guard_spike_factor,
-        checkpoint_dir=args.checkpoint_dir,
-        checkpoint_every=args.checkpoint_every,
-        resume=args.resume,
-        sanitize=args.sanitize,
-        sanitize_nans=args.sanitize_nans,
-        run_dir=args.run_dir,
-        log_every=args.log_every,
-        profile_steps=args.profile_steps,
-    )
-    corpus = MarkovCorpus(cfg.vocab_size, seed=1)
-    result = run_training(cfg, s, corpus, log=print)
+    enable_compile_cache()
+    _, _, _, result = train(args)
     print(f"final eval loss: {result['final_eval']:.4f} "
           f"(comm rounds: {result['comm_rounds']}, tokens: {result['tokens']}, "
           f"skipped rounds: {result['skipped_rounds']}, "

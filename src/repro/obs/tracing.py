@@ -101,28 +101,22 @@ class ProfileWindow:
         self.steps = steps
         self.out_dir = out_dir
         self.active = False
-        self.failed = False
 
     def tick(self, step: int) -> None:
-        """Call once per outer step, before running it."""
-        if self.steps is None or self.failed:
+        """Call once per outer step, before running it.  A profiler that
+        cannot start raises: a requested trace is never silently missing."""
+        if self.steps is None:
             return
         a, b = self.steps
         if not self.active and a <= step <= b:
-            try:
-                jax.profiler.start_trace(self.out_dir)
-                self.active = True
-            except Exception:
-                self.failed = True  # profiler unavailable on this backend
+            jax.profiler.start_trace(self.out_dir)
+            self.active = True
         elif self.active and step > b:
             self._stop()
 
     def _stop(self) -> None:
-        try:
-            jax.profiler.stop_trace()
-        except Exception:
-            pass
         self.active = False
+        jax.profiler.stop_trace()
 
     def close(self) -> None:
         if self.active:

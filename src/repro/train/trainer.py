@@ -1,8 +1,10 @@
 """Training harness: runs any algorithm (DSM or baseline) on any ModelConfig.
 
-This is the engine behind the paper-reproduction experiments (benchmarks/)
-and the runnable examples.  CPU-scale by design: reduced configs, simulated
-workers (leading W axis).
+This is the engine behind ``python -m repro.launch.train``, the
+paper-reproduction experiments (benchmarks/), the runnable examples and
+``chip_smoke.py``.  It runs on whatever devices JAX finds: TPU chips, or the
+CPU for tests.  Workers are a leading W axis, vmapped on one device or
+shard_mapped one per device (``device_parallel_local``).
 """
 
 from __future__ import annotations
@@ -56,6 +58,8 @@ class TrainSettings:
     dsm_wd: float = 0.1
     sign_mode: str = "sign"
     seed: int = 0
+    remat: bool = False             # recompute each layer's activations in
+    #                                 the backward pass (TopologyConfig.remat)
     eval_every: int = 10
     eval_batch: int = 16
     heterogeneous: bool = True
@@ -224,7 +228,7 @@ def run_training(cfg, s: TrainSettings, corpus=None, log: Optional[Callable] = N
     params = T.init_params(key, cfg)
 
     def loss_fn(p, mb):
-        return T.loss_fn(p, mb, cfg, remat=False)
+        return T.loss_fn(p, mb, cfg, remat=s.remat)
 
     # ONE mesh construction for every mesh-consuming feature: zero_sharded,
     # device_parallel_local, and whatever comes next all share this path
@@ -254,10 +258,12 @@ def run_training(cfg, s: TrainSettings, corpus=None, log: Optional[Callable] = N
     # distinct compile-log names so the sanitizer's recompilation counter can
     # tell the outer step from the (also jitted) eval loss
     step_fn.__name__ = "train_step"
-    jstep = jax.jit(step_fn)
+    # the state (and guard state) is donated: the outer step updates it in
+    # place, so a full-width model holds one copy of it, not two
+    jstep = jax.jit(step_fn, donate_argnums=(0, 1) if guards_on else (0,))
 
     def eval_loss(p, b):
-        return T.loss_fn(p, b, cfg, remat=False)
+        return loss_fn(p, b)
 
     eval_loss_fn = jax.jit(eval_loss)
 
@@ -537,7 +543,8 @@ def run_training(cfg, s: TrainSettings, corpus=None, log: Optional[Callable] = N
             step_args = ((state, guard, probe_batch, probe_key, probe_fr)
                          if guards_on
                          else (state, probe_batch, probe_key, probe_fr))
-            step_s = OT.timeit_fenced(jstep, *step_args, iters=3)
+            # re-feeds the same state, so it needs an undonated jit
+            step_s = OT.timeit_fenced(jax.jit(step_fn), *step_args, iters=3)
             phase_totals.add("local_phase", local_s)
             phase_totals.add("global_step", max(step_s - local_s, 0.0))
             writer.span("local_phase", local_s, probe=True)

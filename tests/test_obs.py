@@ -169,6 +169,18 @@ def test_runwriter_roundtrip_and_resume(tmp_path):
     assert [r["step"] for r in rows] == [1, 2]
 
 
+def test_profile_window_raises_when_profiler_cannot_start(monkeypatch, tmp_path):
+    def refuse(out_dir):
+        raise RuntimeError("profiler unavailable")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    win = OT.ProfileWindow((1, 2), str(tmp_path))
+    win.tick(0)  # before the window: nothing starts
+    with pytest.raises(RuntimeError, match="profiler unavailable"):
+        win.tick(1)
+    assert not win.active
+
+
 def test_tracing_primitives():
     assert OT.parse_profile_steps(None) is None
     assert OT.parse_profile_steps("3:7") == (3, 7)
@@ -334,6 +346,25 @@ def test_instrumented_run_passes_sanitizers(tmp_path):
     r = run_training(NANO, s, MarkovCorpus(NANO.vocab_size, seed=7))
     assert r["step_compiles"] == 1
     assert np.isfinite(r["final_eval"])
+
+
+def test_donated_remat_step_matches_and_probe_survives(tmp_path):
+    """The outer step takes its state donated.  With remat on it gives the
+    losses it gives with remat off, and the post-run probe, which feeds one
+    state to the step several times, still runs."""
+    from repro.data.pipeline import MarkovCorpus
+    from repro.train.trainer import TrainSettings, run_training
+
+    hist = {}
+    for remat in (False, True):
+        s = TrainSettings(algorithm="dsm", n_workers=2, tau=2, steps=2,
+                          b_micro=2, seq=32, eval_every=2, remat=remat,
+                          run_dir=str(tmp_path / f"remat_{remat}"))
+        r = run_training(NANO, s, MarkovCorpus(NANO.vocab_size, seed=7))
+        hist[remat] = r["history"]
+        assert r["phase_ms"]["global_step"]["count"] == 1
+        assert r["phase_ms"]["local_phase"]["count"] == 1
+    np.testing.assert_allclose(hist[True], hist[False], rtol=1e-6)
 
 
 def test_baseline_rows_have_nan_dsm_slots(tmp_path):

@@ -26,6 +26,43 @@ def test_markov_corpus_shapes_and_determinism():
     assert r1.min() >= 0 and r1.max() < 100
 
 
+def test_markov_corpus_scales_to_gpt2_vocab():
+    """Transitions are hashed, not tabled: a 50,257-token chain costs a few
+    MB and well under a second to build, whatever the vocabulary."""
+    import time
+    import tracemalloc
+
+    tracemalloc.start()
+    t0 = time.perf_counter()
+    c = MarkovCorpus(50257, seed=1)
+    seconds = time.perf_counter() - t0
+    peak = tracemalloc.get_traced_memory()[1]
+    tracemalloc.stop()
+    assert seconds < 0.5 and peak < 4 * 2**20, (seconds, peak)
+    r1 = c.sample(np.random.default_rng(0), 8, 256)
+    r2 = MarkovCorpus(50257, seed=1).sample(np.random.default_rng(0), 8, 256)
+    np.testing.assert_array_equal(r1, r2)
+    r3 = MarkovCorpus(50257, seed=2).sample(np.random.default_rng(0), 8, 256)
+    assert not np.array_equal(r1[:, 2:], r3[:, 2:])
+    assert r1.min() >= 0 and r1.max() < 50257
+    # tokens reach the top of the range, not just a low slice of it
+    assert r1.max() > 50257 * 0.9
+
+
+def test_compile_cache_dir_defers_to_env(monkeypatch):
+    """The launcher's compile cache is a fixed directory in the checkout,
+    unless JAX_COMPILATION_CACHE_DIR names one (JAX then reads it)."""
+    from repro.launch import train as LT
+
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere")
+    assert LT.compile_cache_dir() is None
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    path = LT.compile_cache_dir()
+    root = os.path.abspath(os.path.join(os.path.dirname(__file__), os.pardir))
+    assert path == os.path.join(root, ".jax_cache")
+    assert LT.compile_cache_dir() == path
+
+
 def test_markov_corpus_is_learnable_structure():
     """An order-2 table must make the chain's bigram-conditional entropy
     far below uniform — i.e. there's signal for training curves."""
