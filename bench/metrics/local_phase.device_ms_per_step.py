@@ -1,0 +1,12 @@
+"""Device milliseconds per outer step in the program's ``dsm_local_phase``
+scope (the tau local steps of every worker), averaged over the chips."""
+
+from harness import trace as TR
+
+
+def read(run):
+    per_chip = [TR.scope_ns(ops, run.op_names, "dsm_local_phase")
+                for ops in run.trace.devices.values()]
+    if not any(per_chip):
+        return None
+    return sum(per_chip) / len(per_chip) * 1e-6 / run.steps
