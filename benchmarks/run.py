@@ -19,7 +19,7 @@ def main() -> None:
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--only", default="all",
                     help="comma list: table2,table3,table45,table6,curves,comm,"
-                         "kernels,perf")
+                         "kernels")
     args, _ = ap.parse_known_args()
     only = set(args.only.split(","))
 
@@ -27,15 +27,6 @@ def main() -> None:
         return "all" in only or x in only
 
     from benchmarks import comm, kernel_bench, tables
-
-    if want("perf"):
-        from benchmarks import perf
-
-        snap = perf.perf_snapshot(steps=8 if args.quick else 12)
-        path = perf.write_snapshot(snap)
-        _emit("perf_steps_per_s", f"{snap['steps_per_s']:.3f}", path)
-        _emit("perf_tokens_per_s", f"{snap['tokens_per_s']:.0f}",
-              ";".join(f"{k}={v}ms" for k, v in sorted(snap["phase_ms"].items())))
 
     if want("kernels"):
         for fn in (kernel_bench.bench_dsm_kernel, kernel_bench.bench_adamw_kernel,

@@ -314,13 +314,14 @@ def make_local_phase(
                     loss = loss_sum / acc
                 else:
                     loss, grads = grad_fn(p, mb)
-                d, new_bs = base_opt.direction(grads, bs, p, inner0 + k)
-                new_p = jax.tree.map(
-                    lambda x, dd: (
-                        x.astype(jnp.float32) - gamma * dd.astype(jnp.float32)
-                    ).astype(x.dtype),
-                    p, d,
-                )
+                with jax.named_scope("base_opt"):
+                    d, new_bs = base_opt.direction(grads, bs, p, inner0 + k)
+                    new_p = jax.tree.map(
+                        lambda x, dd: (
+                            x.astype(jnp.float32) - gamma * dd.astype(jnp.float32)
+                        ).astype(x.dtype),
+                        p, d,
+                    )
                 return new_p, new_bs, loss
 
             new_params, new_base, losses = jax.vmap(per_worker)(

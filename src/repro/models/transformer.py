@@ -16,6 +16,7 @@ Public API (used by trainer / dryrun / serve):
 
 from __future__ import annotations
 
+import contextlib
 import math
 from typing import Any
 
@@ -111,59 +112,67 @@ def _apply_block(p, kind, x, positions, cfg, enc_out=None, collect_cache=False):
     aux = jnp.zeros((), F32)
     cache_entry = None
 
-    h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
-    if mixer in ("attn", "swa"):
-        if cfg.attn_seq_shard:
-            from jax.sharding import PartitionSpec as _P
+    # Named scopes reach the compiled HLO's op_name, so a profile can tell
+    # the attention sublayer and the MLP apart (docs/observability.md).
+    attn_scope = (jax.named_scope("attention") if mixer in ("attn", "swa")
+                  else contextlib.nullcontext())
+    with attn_scope:
+        h = L.rmsnorm(p["ln1"], x, cfg.norm_eps)
+        if mixer in ("attn", "swa"):
+            if cfg.attn_seq_shard:
+                from jax.sharding import PartitionSpec as _P
 
-            h = jax.lax.with_sharding_constraint(h, _P(None, "model", None))
-        q, k, v = L.attn_qkv(p["attn"], h, positions, cfg)
-        window = cfg.window if mixer == "swa" else None
-        out = L.causal_attention(q, k, v, window=window, q_block=cfg.q_block)
-        if cfg.attn_seq_shard:
-            from jax.sharding import PartitionSpec as _P
+                h = jax.lax.with_sharding_constraint(h, _P(None, "model", None))
+            q, k, v = L.attn_qkv(p["attn"], h, positions, cfg)
+            window = cfg.window if mixer == "swa" else None
+            out = L.causal_attention(q, k, v, window=window, q_block=cfg.q_block)
+            if cfg.attn_seq_shard:
+                from jax.sharding import PartitionSpec as _P
 
-            out = jax.lax.with_sharding_constraint(
-                out, _P(None, "model", None, None))
-        x = x + L.attn_proj_out(p["attn"], out)
-        if collect_cache:
-            if mixer == "swa":
-                w = min(cfg.window, k.shape[1])
-                cache_entry = {"k": k[:, -w:], "v": v[:, -w:]}
-            else:
-                cache_entry = {"k": k, "v": v}
-    elif mixer == "encattn":
-        q, k, v = L.attn_qkv(p["attn"], h, positions, cfg)
-        out = L.full_attention(q, k, v)
-        x = x + L.attn_proj_out(p["attn"], out)
-    elif mixer == "xattn":
-        q, k, v = L.attn_qkv(p["attn"], h, positions, cfg)
-        out = L.causal_attention(q, k, v, q_block=cfg.q_block)
-        x = x + L.attn_proj_out(p["attn"], out)
-        hx = L.rmsnorm(p["lnx"], x, cfg.norm_eps)
-        B, Se, _ = enc_out.shape
-        qx = (hx @ p["xattn"]["wq"].astype(hx.dtype)).reshape(
-            B, hx.shape[1], cfg.n_heads, cfg.hd
-        )
-        kx = (enc_out @ p["xattn"]["wk"].astype(hx.dtype)).reshape(B, Se, cfg.n_kv_heads, cfg.hd)
-        vx = (enc_out @ p["xattn"]["wv"].astype(hx.dtype)).reshape(B, Se, cfg.n_kv_heads, cfg.hd)
-        out = L.full_attention(qx, kx, vx)
-        x = x + L.attn_proj_out(p["xattn"], out)
-        if collect_cache:
-            cache_entry = {"k": k, "v": v, "kx": kx, "vx": vx}
-    elif mixer == "ssm":
-        out = L.mamba2_apply(p["ssm"], h, cfg)
-        x = x + out
-        if collect_cache:
-            cache_entry = "ssm_final"  # filled by caller (needs final state)
-    elif mixer == "rglru":
-        out = L.rglru_apply(p["rglru"], h, cfg)
-        x = x + out
-        if collect_cache:
-            cache_entry = "rglru_final"
+                out = jax.lax.with_sharding_constraint(
+                    out, _P(None, "model", None, None))
+            x = x + L.attn_proj_out(p["attn"], out)
+            if collect_cache:
+                if mixer == "swa":
+                    w = min(cfg.window, k.shape[1])
+                    cache_entry = {"k": k[:, -w:], "v": v[:, -w:]}
+                else:
+                    cache_entry = {"k": k, "v": v}
+        elif mixer == "encattn":
+            q, k, v = L.attn_qkv(p["attn"], h, positions, cfg)
+            out = L.full_attention(q, k, v)
+            x = x + L.attn_proj_out(p["attn"], out)
+        elif mixer == "xattn":
+            q, k, v = L.attn_qkv(p["attn"], h, positions, cfg)
+            out = L.causal_attention(q, k, v, q_block=cfg.q_block)
+            x = x + L.attn_proj_out(p["attn"], out)
+            hx = L.rmsnorm(p["lnx"], x, cfg.norm_eps)
+            B, Se, _ = enc_out.shape
+            qx = (hx @ p["xattn"]["wq"].astype(hx.dtype)).reshape(
+                B, hx.shape[1], cfg.n_heads, cfg.hd
+            )
+            kx = (enc_out @ p["xattn"]["wk"].astype(hx.dtype)).reshape(
+                B, Se, cfg.n_kv_heads, cfg.hd)
+            vx = (enc_out @ p["xattn"]["wv"].astype(hx.dtype)).reshape(
+                B, Se, cfg.n_kv_heads, cfg.hd)
+            out = L.full_attention(qx, kx, vx)
+            x = x + L.attn_proj_out(p["xattn"], out)
+            if collect_cache:
+                cache_entry = {"k": k, "v": v, "kx": kx, "vx": vx}
+        elif mixer == "ssm":
+            out = L.mamba2_apply(p["ssm"], h, cfg)
+            x = x + out
+            if collect_cache:
+                cache_entry = "ssm_final"  # filled by caller (needs final state)
+        elif mixer == "rglru":
+            out = L.rglru_apply(p["rglru"], h, cfg)
+            x = x + out
+            if collect_cache:
+                cache_entry = "rglru_final"
 
     if ffn == "dense":
-        x = x + L.mlp_apply(p["mlp"], L.rmsnorm(p["ln2"], x, cfg.norm_eps), cfg)
+        with jax.named_scope("mlp"):
+            x = x + L.mlp_apply(p["mlp"], L.rmsnorm(p["ln2"], x, cfg.norm_eps), cfg)
     elif ffn == "moe":
         out, moe_aux = L.moe_apply(p["moe"], L.rmsnorm(p["ln2"], x, cfg.norm_eps), cfg)
         x = x + out
@@ -299,9 +308,10 @@ def loss_fn(params, batch, cfg, remat: bool = True, unroll: bool = False,
 
     def ce_chunk(carry, inp):
         hcc, tcc, mcc = inp
-        logits = _logits(params, hcc, cfg)
-        lse = jax.nn.logsumexp(logits, axis=-1)
-        gold = jnp.take_along_axis(logits, tcc[..., None], axis=-1)[..., 0]
+        with jax.named_scope("lm_head"):
+            logits = _logits(params, hcc, cfg)
+            lse = jax.nn.logsumexp(logits, axis=-1)
+            gold = jnp.take_along_axis(logits, tcc[..., None], axis=-1)[..., 0]
         nll = (lse - gold) * mcc
         return carry + nll.sum(), None
 

@@ -14,7 +14,15 @@ Layout:
   * ``summarize`` — ``python -m repro.obs summarize <run_dir>`` CLI.
 """
 
-from repro.obs.metrics import (
+import jax
+
+# The device scopes of the outer step (docs/observability.md section 3) are
+# op_name metadata, which JAX's persistent compile cache leaves out of its
+# key by default: an executable cached by a build with other scopes would be
+# served with that build's op_names, and a profile would read stale scopes.
+jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
+
+from repro.obs.metrics import (  # noqa: E402
     IDX,
     METRIC_NAMES,
     N_METRICS,
@@ -23,8 +31,8 @@ from repro.obs.metrics import (
     minimal_pack,
     tree_stat_sums,
 )
-from repro.obs.sinks import RunWriter, build_manifest, read_run
-from repro.obs.summarize import summarize_run
+from repro.obs.sinks import RunWriter, build_manifest, read_run  # noqa: E402
+from repro.obs.summarize import summarize_run  # noqa: E402
 
 __all__ = [
     "IDX",

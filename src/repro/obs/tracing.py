@@ -6,19 +6,22 @@ jitted call measures dispatch, not execution.  ``Span`` fences its exit on
 ``jax.block_until_ready`` over whatever values the caller hands it, which
 makes the wall time honest at the cost of a pipeline bubble — so the
 trainer opens spans around *windows* (a whole log interval, an eval, a
-checkpoint), never around every step.
+checkpoint), never around every step.  Each span is also a
+``jax.profiler.TraceAnnotation`` named ``repro.<name>``, so inside a
+profiler capture it lies on the host plane, on the device ops' clock.
 
 ``ProfileWindow`` arms ``jax.profiler.trace`` for an inclusive step range
 (the ``--profile-steps A:B`` flag); the TensorBoard-loadable capture lands
 in ``<run_dir>/profile``.  ``jax.named_scope`` annotations inside the
-outer step ("dsm_local_phase" / "dsm_global_step") make the two phases
-visible inside that capture even though they live in one fused jit.
+outer step (``dsm_local_phase`` / ``dsm_global_step``, and within the
+local phase ``attention`` / ``mlp`` / ``lm_head`` / ``base_opt``) reach
+the compiled HLO's ``op_name``, which names each device op of a capture.
 """
 
 from __future__ import annotations
 
 import time
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 
@@ -35,19 +38,25 @@ class Span:
         self.seconds = 0.0
         self._fence = list(fence)
         self._t0 = 0.0
+        self._annotation = None
 
     def add_fence(self, *values: Any) -> None:
         self._fence.extend(values)
 
     def __enter__(self) -> "Span":
+        self._annotation = jax.profiler.TraceAnnotation(f"repro.{self.name}")
+        self._annotation.__enter__()
         self._t0 = time.monotonic()
         return self
 
     def __exit__(self, exc_type: Any, *exc: Any) -> None:
-        if exc_type is None and self._fence:
-            jax.block_until_ready(self._fence)
-        self.seconds = time.monotonic() - self._t0
-        self._fence = []
+        try:
+            if exc_type is None and self._fence:
+                jax.block_until_ready(self._fence)
+            self.seconds = time.monotonic() - self._t0
+            self._fence = []
+        finally:
+            self._annotation.__exit__(exc_type, *exc)
 
 
 class PhaseTotals:
@@ -141,16 +150,3 @@ def device_memory_stats() -> Optional[Dict[str, Any]]:
         }
     return out or None
 
-
-def timeit_fenced(fn: Callable[..., Any], *args: Any, iters: int = 5,
-                  warmup: int = 1) -> float:
-    """Median fenced seconds per call (used by the perf snapshot)."""
-    for _ in range(max(warmup, 0)):
-        jax.block_until_ready(fn(*args))
-    times = []
-    for _ in range(max(iters, 1)):
-        t0 = time.monotonic()
-        jax.block_until_ready(fn(*args))
-        times.append(time.monotonic() - t0)
-    times.sort()
-    return times[len(times) // 2]

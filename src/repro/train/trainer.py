@@ -340,7 +340,6 @@ def run_training(cfg, s: TrainSettings, corpus=None, log: Optional[Callable] = N
     writer = None
     profile = None
     phase_totals = None
-    probe_batch = probe_key = probe_fr = None
     log_every = s.log_every if s.log_every > 0 else s.eval_every
     pending: list = []  # (outer step number, on-device metrics dict)
     if obs_on:
@@ -379,7 +378,8 @@ def run_training(cfg, s: TrainSettings, corpus=None, log: Optional[Callable] = N
         nonlocal window_t0, window_steps
         if not pending:
             return None
-        fetched = jax.device_get([m for _, m in pending])
+        with jax.profiler.TraceAnnotation("repro.flush"):
+            fetched = jax.device_get([m for _, m in pending])
         if obs_on and window_steps:
             dt = time.monotonic() - window_t0
             phase_totals.add("train_window", dt, n=window_steps)
@@ -427,85 +427,91 @@ def run_training(cfg, s: TrainSettings, corpus=None, log: Optional[Callable] = N
         while t < s.steps:
             if profile is not None:
                 profile.tick(t)
-            key, sub = jax.random.split(key)
-            batch = prep_batch(next(batches))
-            fr = plan.round(t) if plan is not None else None
-            with step_guard():
-                if guards_on:
-                    state, guard, metrics = jstep(state, guard, batch, sub, fr)
-                else:
-                    state, metrics = jstep(state, batch, sub, fr)
-                # device scalars: fetched only at eval/log/checkpoint points
-                # (the old float() here blocked on the device every outer step)
-                history.append(metrics["loss"])
-                pending.append((t + 1, metrics))
-                window_steps += 1
+            # host annotations on the profiler's clock (docs/observability.md
+            # section 3): one step annotation per outer step, and within it
+            # the input wait, the dispatch and the metric flush
+            with jax.profiler.StepTraceAnnotation("repro.step", step_num=t):
+                key, sub = jax.random.split(key)
+                with jax.profiler.TraceAnnotation("repro.input"):
+                    batch = prep_batch(next(batches))
+                fr = plan.round(t) if plan is not None else None
+                with step_guard(), jax.profiler.TraceAnnotation("repro.dispatch"):
+                    if guards_on:
+                        state, guard, metrics = jstep(state, guard, batch, sub, fr)
+                    else:
+                        state, metrics = jstep(state, batch, sub, fr)
+                    # device scalars: fetched only at eval/log/checkpoint points
+                    # (the old float() here blocked on the device every outer step)
+                    history.append(metrics["loss"])
+                    pending.append((t + 1, metrics))
+                    window_steps += 1
 
-            if rollback_on and int(guard.bad_streak) >= s.guard_patience:
-                # the ONE per-round host read rollback requires (a scalar i32)
-                row = flush_metrics()  # rejected rounds are still observations
-                last_row = row or last_row
-                if rollbacks >= s.guard_max_rollbacks:
-                    raise RuntimeError(
-                        f"training diverged: {int(guard.bad_streak)} consecutive "
-                        f"bad rounds at step {t} after {rollbacks} rollbacks")
-                rollbacks += 1
-                tree, t_ck, extra = CK.restore_latest(
-                    s.checkpoint_dir, ckpt_tree(state, guard, key))
-                state, key = reshard(tree["state"]), tree["key"]
-                guard = tree["guard"]._replace(bad_streak=jnp.zeros((), jnp.int32))
-                history = [float(x) for x in extra.get("history", [])]  # rollback = a sync point
-                evals = [tuple(e) for e in extra.get("evals", [])]
-                if writer is not None:
-                    writer.event("rollback", step=t, to_step=t_ck, n=rollbacks)
-                if log:
-                    log(f"rollback #{rollbacks}: step {t} -> checkpoint at {t_ck}")
-                batches = make_batches(t_ck)
-                t = t_ck
-                window_t0 = time.monotonic()
-                continue
+                if rollback_on and int(guard.bad_streak) >= s.guard_patience:
+                    # the ONE per-round host read rollback requires (a scalar i32)
+                    row = flush_metrics()  # rejected rounds are still observations
+                    last_row = row or last_row
+                    if rollbacks >= s.guard_max_rollbacks:
+                        raise RuntimeError(
+                            f"training diverged: {int(guard.bad_streak)} consecutive "
+                            f"bad rounds at step {t} after {rollbacks} rollbacks")
+                    rollbacks += 1
+                    tree, t_ck, extra = CK.restore_latest(
+                        s.checkpoint_dir, ckpt_tree(state, guard, key))
+                    state, key = reshard(tree["state"]), tree["key"]
+                    guard = tree["guard"]._replace(bad_streak=jnp.zeros((), jnp.int32))
+                    # rollback = a sync point
+                    history = [float(x) for x in extra.get("history", [])]
+                    evals = [tuple(e) for e in extra.get("evals", [])]
+                    if writer is not None:
+                        writer.event("rollback", step=t, to_step=t_ck, n=rollbacks)
+                    if log:
+                        log(f"rollback #{rollbacks}: step {t} -> checkpoint at {t_ck}")
+                    batches = make_batches(t_ck)
+                    t = t_ck
+                    window_t0 = time.monotonic()
+                    continue
 
-            t += 1
-            is_eval = t % s.eval_every == 0 or t == s.steps
-            is_log = t % log_every == 0
-            did_ckpt = ckpt_on and t % ckpt_every == 0
-            if is_eval or is_log or did_ckpt:
-                # metric flush: ONE async fetch covering every round since
-                # the last sync point, with a step-consistent row to log
-                row = flush_metrics()
-                last_row = row or last_row
-            if is_eval:
-                if obs_on:
-                    with OT.Span("eval") as sp:  # float() is the fence
+                t += 1
+                is_eval = t % s.eval_every == 0 or t == s.steps
+                is_log = t % log_every == 0
+                did_ckpt = ckpt_on and t % ckpt_every == 0
+                if is_eval or is_log or did_ckpt:
+                    # metric flush: ONE async fetch covering every round since
+                    # the last sync point, with a step-consistent row to log
+                    row = flush_metrics()
+                    last_row = row or last_row
+                if is_eval:
+                    if obs_on:
+                        with OT.Span("eval") as sp:  # float() is the fence
+                            el = float(eval_loss_fn(eval_params(state), ev_batch))
+                        phase_totals.add("eval", sp.seconds)
+                        writer.span("eval", sp.seconds, step=t)
+                        writer.event("eval", step=t, eval_loss=el)
+                    else:
                         el = float(eval_loss_fn(eval_params(state), ev_batch))
-                    phase_totals.add("eval", sp.seconds)
-                    writer.span("eval", sp.seconds, step=t)
-                    writer.event("eval", step=t, eval_loss=el)
-                else:
-                    el = float(eval_loss_fn(eval_params(state), ev_batch))
-                evals.append((t, el))
-                if log:
-                    train = last_row["loss"] if last_row else float(history[-1])
-                    log(f"step {t:4d} train={train:.4f} eval={el:.4f}")
-            elif is_log and log and last_row is not None:
-                log(f"step {t:4d} train={last_row['loss']:.4f}")
-            if did_ckpt:
-                history = [float(x) for x in history]  # checkpoint = a sync point
-                if obs_on:
-                    with OT.Span("checkpoint", state) as sp:
+                    evals.append((t, el))
+                    if log:
+                        train = last_row["loss"] if last_row else float(history[-1])
+                        log(f"step {t:4d} train={train:.4f} eval={el:.4f}")
+                elif is_log and log and last_row is not None:
+                    log(f"step {t:4d} train={last_row['loss']:.4f}")
+                if did_ckpt:
+                    history = [float(x) for x in history]  # checkpoint = a sync point
+                    if obs_on:
+                        with OT.Span("checkpoint", state) as sp:
+                            CK.save_checkpoint(
+                                s.checkpoint_dir, ckpt_tree(state, guard, key), t,
+                                keep=s.checkpoint_keep, extra=ckpt_extra())
+                        phase_totals.add("checkpoint", sp.seconds)
+                        writer.span("checkpoint", sp.seconds, step=t)
+                        writer.event("checkpoint", step=t)
+                    else:
                         CK.save_checkpoint(
                             s.checkpoint_dir, ckpt_tree(state, guard, key), t,
                             keep=s.checkpoint_keep, extra=ckpt_extra())
-                    phase_totals.add("checkpoint", sp.seconds)
-                    writer.span("checkpoint", sp.seconds, step=t)
-                    writer.event("checkpoint", step=t)
-                else:
-                    CK.save_checkpoint(
-                        s.checkpoint_dir, ckpt_tree(state, guard, key), t,
-                        keep=s.checkpoint_keep, extra=ckpt_extra())
-            if obs_on and (is_eval or is_log or did_ckpt):
-                # eval/checkpoint time must not leak into the next train window
-                window_t0 = time.monotonic()
+                if obs_on and (is_eval or is_log or did_ckpt):
+                    # eval/checkpoint time must not leak into the next train window
+                    window_t0 = time.monotonic()
     finally:
         loop_ctx.close()
         if profile is not None:
@@ -522,33 +528,6 @@ def run_training(cfg, s: TrainSettings, corpus=None, log: Optional[Callable] = N
     phase_ms = None
     if obs_on:
         steps_done = t - start_step
-        # post-run phase probe: local phase and full outer step cannot be
-        # separately fenced in-loop (one fused jit), so re-time both fenced
-        # here; global step = outer step - local phase.  The probe fns get
-        # their own jits/names so the recompilation counter (already closed)
-        # and its steady-state assertion never see them.
-        if s.algorithm in _DSM_FAMILY and steps_done > 0:
-            from repro.core import make_local_phase
-
-            lp = make_local_phase(
-                loss_fn, get_base_optimizer(s.base_opt), accum=True,
-                device_parallel=s.device_parallel_local, mesh=mesh)
-
-            def local_phase_probe(p, bs, b):
-                return lp(p, bs, b, jnp.float32(s.peak_lr), jnp.int32(0))
-
-            local_s = OT.timeit_fenced(
-                jax.jit(local_phase_probe),
-                state.params, state.base_state, probe_batch, iters=3)
-            step_args = ((state, guard, probe_batch, probe_key, probe_fr)
-                         if guards_on
-                         else (state, probe_batch, probe_key, probe_fr))
-            # re-feeds the same state, so it needs an undonated jit
-            step_s = OT.timeit_fenced(jax.jit(step_fn), *step_args, iters=3)
-            phase_totals.add("local_phase", local_s)
-            phase_totals.add("global_step", max(step_s - local_s, 0.0))
-            writer.span("local_phase", local_s, probe=True)
-            writer.span("global_step", max(step_s - local_s, 0.0), probe=True)
         mem = OT.device_memory_stats()
         if mem is not None:
             writer.event("device_memory", stats=mem)

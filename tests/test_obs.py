@@ -3,7 +3,8 @@
 Covers the on-device metric pack (jit/eager parity, budget-free sanity),
 the run sinks (JSONL/CSV round-trip, resume append, truncated-tail
 tolerance), the summarize CLI against a REAL instrumented smoke run,
-guard-counter persistence across --resume, the perf snapshot, and — in the
+guard-counter persistence across --resume, the layer scopes in the compiled
+outer step and the host annotations in a profiler capture, and — in the
 8-forced-device subprocess tier — sharded-vs-dense pack parity plus a
 non-degenerate comm ledger (observed collective bytes with ratios).
 
@@ -14,6 +15,7 @@ hot loop and the steady-state outer step still compiles exactly once.
 
 import json
 import os
+import re
 import subprocess
 import sys
 
@@ -203,6 +205,123 @@ def test_tracing_primitives():
 
 
 # ---------------------------------------------------------------------------
+# tracing: layer scopes in the compiled outer step, host annotations in a
+# profiler capture (docs/observability.md section 3)
+# ---------------------------------------------------------------------------
+
+LAYER_SCOPES = ("attention", "mlp", "lm_head", "base_opt")
+_INSTR = re.compile(r'^\s*(?:ROOT\s+)?%?([\w.\-]+)\s*=.*?op_name="([^"]*)"')
+_WRAPPED = re.compile(r"^[\w.\-]+\((.*)\)$")
+
+
+def _carries(op_name, scope):
+    """``scope`` is a component of the path, bare or wrapped in transform
+    names (``vmap(base_opt)``, ``transpose(jvp(lm_head))``)."""
+    for part in op_name.split("/"):
+        m = _WRAPPED.match(part)
+        while m:
+            part = m.group(1)
+            m = _WRAPPED.match(part)
+        if part == scope:
+            return True
+    return False
+
+
+def _outer_step_op_names(remat):
+    """Instruction -> ``op_name`` of the compiled DSM outer step at NANO."""
+    from repro.data.pipeline import MarkovCorpus, dsm_batches
+    from repro.models import transformer as T
+    from repro.train.trainer import TrainSettings, build_algorithm
+
+    s = TrainSettings(algorithm="dsm", n_workers=2, tau=2, b_micro=2, seq=32,
+                      remat=remat)
+    init, step, _, _ = build_algorithm(
+        lambda p, mb: T.loss_fn(p, mb, NANO, remat=remat), s)
+    state = init(T.init_params(jax.random.PRNGKey(0), NANO), s.n_workers)
+    batch = jax.tree.map(jnp.asarray, next(dsm_batches(
+        MarkovCorpus(NANO.vocab_size, seed=7), s.n_workers, s.tau, 1,
+        s.b_micro, s.seq, seed=0)))
+
+    def train_step(state, batch, key):
+        return step(state, batch, key)
+
+    text = jax.jit(train_step).lower(
+        state, batch, jax.random.PRNGKey(1)).compile().as_text()
+    return dict(m.groups() for m in map(_INSTR.match, text.splitlines()) if m)
+
+
+@pytest.mark.parametrize("remat", [False, True])
+def test_outer_step_op_names_carry_the_layer_scopes(remat):
+    """The four local-phase scopes reach the compiled HLO in every form a
+    profile meets them in, inside ``dsm_local_phase``, and no instruction
+    carries two of them (the scope readers' sums stay disjoint).  Reducer
+    regions carry a path cut short of ``jit(...)``; they run inside the
+    instruction that calls them."""
+    names = _outer_step_op_names(remat)
+    forms = {scope: set() for scope in LAYER_SCOPES}
+    for instr, path in names.items():
+        carried = [scope for scope in LAYER_SCOPES if _carries(path, scope)]
+        assert len(carried) <= 1, (instr, path)
+        if carried and path.startswith("jit("):
+            assert _carries(path, "dsm_local_phase"), path
+            forms[carried[0]].add(
+                "recompute" if _carries(path, "rematted_computation")
+                else "backward" if "transpose(" in path else "forward")
+    layer_forms = {"forward", "backward"} | ({"recompute"} if remat else set())
+    assert forms["attention"] == layer_forms
+    assert forms["mlp"] == layer_forms
+    # the cross-entropy lies outside the checkpointed layers: never recomputed
+    assert forms["lm_head"] == {"forward", "backward"}
+    # opened directly under vmap, so it shows only wrapped: vmap(base_opt)
+    assert forms["base_opt"] == {"forward"}
+    assert any("vmap(base_opt)" in p for p in names.values())
+
+
+def _host_events(trace_dir):
+    """The events on the host plane of a capture."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    found = glob.glob(os.path.join(str(trace_dir), "**", "*.xplane.pb"),
+                      recursive=True)
+    assert len(found) == 1, found
+    data = ProfileData.from_file(found[0])
+    return [e for plane in data.planes if plane.name == "/host:CPU"
+            for line in plane.lines for e in line.events]
+
+
+def test_span_lands_on_the_profiler_host_plane(tmp_path):
+    x = jnp.ones((4,))
+    with jax.profiler.trace(str(tmp_path)):
+        with OT.Span("probe_window", x) as sp:
+            sp.add_fence(x * 2)
+    assert sp.seconds >= 0.0
+    assert [e.name for e in _host_events(tmp_path)
+            if e.name.startswith("repro.")] == ["repro.probe_window"]
+
+
+def test_profile_steps_capture_holds_the_step_annotations(tmp_path):
+    """``--profile-steps 1:2`` captures outer steps 1 and 2 with their
+    input, dispatch and flush annotations and the eval span."""
+    from repro.data.pipeline import MarkovCorpus
+    from repro.train.trainer import TrainSettings, run_training
+
+    s = TrainSettings(algorithm="dsm", n_workers=2, tau=2, steps=4,
+                      b_micro=2, seq=32, eval_every=2, log_every=1,
+                      run_dir=str(tmp_path / "run"), profile_steps="1:2")
+    run_training(NANO, s, MarkovCorpus(NANO.vocab_size, seed=7))
+    events = _host_events(tmp_path / "run" / "profile")
+    steps = [dict(e.stats)["step_num"] for e in events
+             if e.name == "repro.step"]
+    assert sorted(steps) == [1, 2]
+    names = [e.name for e in events]
+    for name in ("repro.input", "repro.dispatch", "repro.flush"):
+        assert names.count(name) == 2, name
+    assert names.count("repro.eval") == 1
+
+
+# ---------------------------------------------------------------------------
 # comm model: the analytic side of the ledger
 # ---------------------------------------------------------------------------
 
@@ -274,7 +393,7 @@ def test_smoke_run_dir_contents(smoke_run):
     assert ledger["degenerate_mesh"]  # 1-device host: ratios suppressed
     assert ledger["ratio"]["reduce"] is None
     span_names = {e["name"] for e in events if e["kind"] == "span"}
-    assert {"train_window", "eval", "local_phase", "global_step"} <= span_names
+    assert {"train_window", "eval"} <= span_names
     fin = next(e for e in events if e["kind"] == "finished")
     assert fin["steps"] == s.steps and fin["tokens"] == result["tokens"]
     assert result["phase_ms"] is not None
@@ -348,10 +467,9 @@ def test_instrumented_run_passes_sanitizers(tmp_path):
     assert np.isfinite(r["final_eval"])
 
 
-def test_donated_remat_step_matches_and_probe_survives(tmp_path):
+def test_donated_remat_step_matches(tmp_path):
     """The outer step takes its state donated.  With remat on it gives the
-    losses it gives with remat off, and the post-run probe, which feeds one
-    state to the step several times, still runs."""
+    losses it gives with remat off."""
     from repro.data.pipeline import MarkovCorpus
     from repro.train.trainer import TrainSettings, run_training
 
@@ -362,8 +480,6 @@ def test_donated_remat_step_matches_and_probe_survives(tmp_path):
                           run_dir=str(tmp_path / f"remat_{remat}"))
         r = run_training(NANO, s, MarkovCorpus(NANO.vocab_size, seed=7))
         hist[remat] = r["history"]
-        assert r["phase_ms"]["global_step"]["count"] == 1
-        assert r["phase_ms"]["local_phase"]["count"] == 1
     np.testing.assert_allclose(hist[True], hist[False], rtol=1e-6)
 
 
@@ -407,19 +523,6 @@ def test_guard_counters_survive_resume(tmp_path):
     assert r2["skipped_rounds"] == 7  # 3 from before the restart + 4 new
     extra = CK.load_meta(CK.latest_checkpoint(ck)).get("extra")
     assert extra["skipped_rounds"] == 7
-
-
-def test_perf_snapshot_smoke(tmp_path):
-    from benchmarks.perf import perf_snapshot, write_snapshot
-
-    snap = perf_snapshot(steps=2, n_workers=2, tau=2,
-                         run_dir=str(tmp_path / "perf"))
-    assert snap["steps_per_s"] > 0 and snap["tokens_per_s"] > 0
-    assert "local_phase" in snap["phase_ms"]
-    path = write_snapshot(snap, out_dir=str(tmp_path))
-    assert os.path.basename(path) == "BENCH_nano_dsm.json"
-    with open(path) as f:
-        assert json.load(f)["steps"] == 2
 
 
 # ---------------------------------------------------------------------------
@@ -496,6 +599,6 @@ def test_sharded_pack_and_ledger_8dev(tmp_path):
     assert ledger["observed"]["reduce_bytes"] > 0
     assert ledger["observed"]["reduce_ops"] > 0
     assert ledger["ratio"]["reduce"] is not None
-    assert {"train_window", "local_phase", "global_step"} <= set(rec["spans"])
+    assert "train_window" in rec["spans"]
     # the summary renders observed-vs-predicted comm volume from real HLO
     assert rec["summary"]["comm_ledger"]["observed"]["reduce_bytes"] > 0
