@@ -15,6 +15,8 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import flash_attention
+
 PyTree = Any
 F32 = jnp.float32
 
@@ -101,19 +103,36 @@ def _gqa_out(probs, v, out_dtype):
     return out.reshape(B, Sq, KVH * rep, v.shape[-1]).astype(out_dtype)
 
 
+def fused_attention_applies(q, k, window: Optional[int] = None,
+                            seq_sharded: bool = False) -> bool:
+    """Whether ``causal_attention`` takes the fused flash-attention kernel:
+    on TPU, full causal multi-head attention (as many k/v heads as query
+    heads) at shapes the kernel supports, with the sequence not sharded by
+    GSPMD (which cannot partition a Pallas call)."""
+    _, S, H, hd = q.shape
+    return (jax.default_backend() == "tpu" and window is None
+            and not seq_sharded and k.shape[2] == H
+            and flash_attention.supported(S, H, hd))
+
+
 def causal_attention(
     q: jnp.ndarray,
     k: jnp.ndarray,
     v: jnp.ndarray,
     window: Optional[int] = None,
     q_block: int = 1024,
+    seq_sharded: bool = False,
 ) -> jnp.ndarray:
-    """Blockwise causal (optionally sliding-window) attention.
+    """Causal (optionally sliding-window) attention.
 
-    Unrolled static loop over query tiles; each tile attends only to the
+    Where ``fused_attention_applies``, one fused Pallas kernel that keeps
+    score tiles in VMEM (``kernels/flash_attention.py``).  Otherwise an
+    unrolled static loop over query tiles; each tile attends only to the
     (block-aligned) keys it can see, so FLOPs match causal/windowed exactly
     (up to one diagonal tile) and the score buffer stays O(q_block * Sk_vis).
     """
+    if fused_attention_applies(q, k, window, seq_sharded):
+        return flash_attention.flash_attention(q, k, v)
     B, S, H, hd = q.shape
     qb = min(q_block, S)
     n_blocks = -(-S // qb)

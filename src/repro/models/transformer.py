@@ -125,7 +125,8 @@ def _apply_block(p, kind, x, positions, cfg, enc_out=None, collect_cache=False):
                 h = jax.lax.with_sharding_constraint(h, _P(None, "model", None))
             q, k, v = L.attn_qkv(p["attn"], h, positions, cfg)
             window = cfg.window if mixer == "swa" else None
-            out = L.causal_attention(q, k, v, window=window, q_block=cfg.q_block)
+            out = L.causal_attention(q, k, v, window=window, q_block=cfg.q_block,
+                                     seq_sharded=cfg.attn_seq_shard)
             if cfg.attn_seq_shard:
                 from jax.sharding import PartitionSpec as _P
 
