@@ -5,8 +5,9 @@ Two modes:
     CPU under ``JAX_PLATFORMS=cpu``):
       PYTHONPATH=src python -m repro.launch.train --arch gpt2_small \\
           --algorithm dsm --tau 12 --seq 1024 --steps 100
-    ``--arch`` accepts ``<id>`` (FULL config at published widths; GPT-2
-    small fits one TPU v5e chip at W=4, b_micro=4, seq=1024),
+    ``--arch`` accepts ``<id>`` (FULL config at published widths; on one
+    TPU v5e chip GPT-2 small fits at W=4 and GPT-2 medium at W=2, each at
+    b_micro=4, seq=1024),
     ``<id>_smoke`` (reduced family variant), or ``nano``.  Rematerialization
     follows the arch's ``TopologyConfig.remat``.
   * plan              — prints the production launch plan for the 16x16 /

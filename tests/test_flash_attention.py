@@ -48,6 +48,7 @@ def _value_and_grads(fn, q, k, v, cot):
     (384, 2, 64),    # the cell's head dim: two heads a step
     (384, 2, 128),   # one head a step; a scale that is no power of two
     (256, 4, 64),    # two head groups
+    (256, 16, 64),   # eight head groups: GPT-2 medium's heads
     (1536, 2, 64),   # forward and backward: 3x3 tiles of 512
     (256, 1, 256),   # a head two lane tiles wide
 ])
@@ -142,6 +143,7 @@ def test_gqa_and_swa_configs_trace_as_before(monkeypatch, arch):
 
 @pytest.mark.parametrize("arch,change,takes", [
     ("gpt2_small", {}, True),
+    ("gpt2_medium", {}, True),
     ("whisper_large_v3", {}, True),     # the decoder's causal self-attention
     ("gpt2_small", dict(attn_seq_shard=True), False),
 ])

@@ -3,7 +3,8 @@
 The TPU compiler refuses what interpret mode and the CPU backend accept: a
 Pallas tile the chip cannot hold, a step that does not fit its HBM.  These
 tests compile the fused DSM kernel at GPT-2 small slab shapes and whole DSM
-outer steps at GPT-2 small widths for one v5e chip, from shapes alone.
+outer steps at GPT-2 small and medium widths for one v5e chip, from shapes
+alone.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, so describing it while the
@@ -60,16 +61,17 @@ def test_dsm_kernel_compiles_for_v5e(one_chip, rows):
     assert "tpu_custom_call" in compiled.as_text()
 
 
-def _compile_gpt2_small_step(one_chip, n_layers):
-    """The trainer's outer step at GPT-2 small widths, W=4 workers, tau=12,
-    b_micro=4, seq=1024, remat on, state donated: (compiled, state shapes)."""
+def _compile_dsm_step(one_chip, arch, n_workers, n_layers):
+    """The trainer's outer step at ``arch``'s published widths, ``n_workers``
+    vmapped workers, tau=12, b_micro=4, seq=1024, remat on, state donated:
+    (compiled, state shapes)."""
     from repro.configs import load_arch
     from repro.models import transformer as T
     from repro.train.trainer import TrainSettings, build_algorithm
 
-    cfg = dataclasses.replace(load_arch("gpt2_small").FULL, n_layers=n_layers)
-    s = TrainSettings(algorithm="dsm", base_opt="adamw", n_workers=4, tau=12,
-                      b_micro=4, seq=1024, remat=True)
+    cfg = dataclasses.replace(load_arch(arch).FULL, n_layers=n_layers)
+    s = TrainSettings(algorithm="dsm", base_opt="adamw", n_workers=n_workers,
+                      tau=12, b_micro=4, seq=1024, remat=True)
 
     def loss_fn(p, mb):
         return T.loss_fn(p, mb, cfg, remat=s.remat)
@@ -86,50 +88,82 @@ def _compile_gpt2_small_step(one_chip, n_layers):
     return compiled, state
 
 
-def _fits_one_v5e(compiled, state):
-    mem = compiled.memory_analysis()
+def _aliases_the_state(mem, state):
     state_bytes = sum(l.size * l.dtype.itemsize for l in jax.tree.leaves(state))
     # donation: the new state is written over the old one
     assert mem.alias_size_in_bytes >= state_bytes
+
+
+def _fits_one_v5e(compiled, state):
+    mem = compiled.memory_analysis()
+    _aliases_the_state(mem, state)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM
 
 
-def test_gpt2_small_dsm_step_fits_one_v5e(one_chip):
-    """2 of the 12 layers, on the einsum attention path (CPU backend)."""
-    _fits_one_v5e(*_compile_gpt2_small_step(one_chip, n_layers=2))
-
-
-def test_gpt2_small_dsm_step_with_flash_attention_fits_one_v5e(one_chip,
-                                                                monkeypatch):
-    """All 12 layers with the fused attention kernel, as the step takes it
-    on a TPU backend: Mosaic accepts its tiles, the step fits, the kernel's
-    calls keep the attention and remat scopes, and no (S, S) score
-    convolution is left."""
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    compiled, state = _compile_gpt2_small_step(one_chip, n_layers=12)
-    _fits_one_v5e(compiled, state)
-    hlo = compiled.as_text()
+def _kernel_path_in_attention(hlo, score_dims=r"[\d,]*1024,1024"):
+    """The fused attention kernel's calls keep the attention and remat
+    scopes, and no (S, S) score convolution (output dims ``score_dims``)
+    is left."""
     calls = [line for line in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     names = [re.search(r'op_name="([^"]*)"', c).group(1) for c in calls]
     # forward, remat forward, fused backward
     assert len(names) == 3 and all("/attention/" in n for n in names), names
     assert sum("rematted_computation" in n for n in names) == 1, names
-    scores = re.compile(r"= \w+\[[\d,]*1024,1024\]\S* convolution")
+    scores = re.compile(rf"= \w+\[{score_dims}\]\S* convolution")
     assert not [line for line in hlo.splitlines() if scores.search(line)]
 
 
-def test_flash_attention_fits_half_the_scoped_vmem(one_chip, monkeypatch):
-    """The kernel's forward and backward at the cell's shapes (W=4 vmapped,
-    B 4, S 1024, 12 heads of 64) within 8 MiB of VMEM, half the default
+def test_gpt2_small_dsm_step_fits_one_v5e(one_chip):
+    """2 of the 12 layers, on the einsum attention path (CPU backend)."""
+    _fits_one_v5e(*_compile_dsm_step(one_chip, "gpt2_small", n_workers=4,
+                                     n_layers=2))
+
+
+def test_gpt2_small_dsm_step_with_flash_attention_fits_one_v5e(one_chip,
+                                                                monkeypatch):
+    """All 12 layers with the fused attention kernel, as the step takes it
+    on a TPU backend: Mosaic accepts its tiles and the step fits."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, state = _compile_dsm_step(one_chip, "gpt2_small", n_workers=4,
+                                        n_layers=12)
+    _fits_one_v5e(compiled, state)
+    _kernel_path_in_attention(compiled.as_text())
+
+
+def test_gpt2_medium_dsm_step_with_flash_attention_fits_one_v5e(one_chip,
+                                                                 monkeypatch):
+    """All 24 layers of GPT-2 medium, W=2, with the fused attention kernel
+    (eight groups of two heads of 64).  Arguments plus temp (16.6 GiB) add
+    up buffers that are never live at once; the bound here is the
+    compiler's own peak of live bytes.  With n_embd = S = 1024 every
+    projection is (W, B, S, 1024) too, so a score product is told by its
+    16-head axis."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    compiled, state = _compile_dsm_step(one_chip, "gpt2_medium", n_workers=2,
+                                        n_layers=24)
+    mem = compiled.memory_analysis()
+    _aliases_the_state(mem, state)
+    assert mem.peak_memory_in_bytes < V5E_HBM
+    _kernel_path_in_attention(compiled.as_text(),
+                              score_dims=r"[\d,]*16,1024,1024")
+
+
+@pytest.mark.parametrize("shape", [
+    (4, 4, 1024, 12, 64),   # gpt2_small.w4.tau12: W=4 vmapped, B 4
+    (2, 4, 1024, 16, 64),   # gpt2_medium.w2.tau12: W=2, eight head groups
+], ids=["gpt2_small", "gpt2_medium"])
+def test_flash_attention_fits_half_the_scoped_vmem(one_chip, monkeypatch,
+                                                   shape):
+    """The kernel's forward and backward at a cell's shapes (W workers
+    vmapped, B, S 1024, heads of 64) within 8 MiB of VMEM, half the default
     scoped limit: inside a whole step the compiler leaves a kernel less
     than it does alone."""
     from repro.kernels import flash_attention as FA
 
     monkeypatch.setattr(FA.pltpu, "CompilerParams", functools.partial(
         FA.pltpu.CompilerParams, vmem_limit_bytes=8 * 2 ** 20))
-    x = jax.ShapeDtypeStruct((4, 4, 1024, 12, 64), jnp.bfloat16,
-                             sharding=one_chip)
+    x = jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)
 
     def fwd_bwd(q, k, v):
         out, vjp = jax.vjp(jax.vmap(FA.flash_attention), q, k, v)
