@@ -44,7 +44,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.configs import INPUT_SHAPES, ARCH_IDS, arch_supports_shape, load_arch
 from repro.configs import specs as S
 from repro.core import DSMConfig, get_base_optimizer
-from repro.core.dsm import _broadcast_workers, global_sign_momentum_step
+from repro.core import workers as WK
+from repro.core.dsm import global_sign_momentum_step
 from repro.distributed import sharding as shd
 from repro.launch import dryrun as DR
 from repro.launch.mesh import MODEL_PAR, make_production_mesh, serving_mesh, training_mesh
@@ -93,7 +94,7 @@ def _train_micro_cost(cfg, topo, shape, mesh, W, zero, n_layers):
     rcfg = _reduced(cfg, n_layers)
     rep = () if topo.attn_tp else ("wq", "wk", "wv", "wo")
     aps = S.abstract_params(rcfg)
-    wparams = jax.eval_shape(lambda p: _broadcast_workers(p, W), aps)
+    wparams = jax.eval_shape(lambda p: WK.broadcast_workers(p, W), aps)
     full = S.train_batch_specs(cfg, topo, shape, W)
     micro = jax.tree.map(
         lambda l: jax.ShapeDtypeStruct((W,) + l.shape[3:], l.dtype), full
@@ -112,7 +113,9 @@ def _train_micro_cost(cfg, topo, shape, mesh, W, zero, n_layers):
                                   remat_policy=getattr(topo, "remat_policy", "full"))
 
     def micro_grad(params_w, mb):
-        return jax.vmap(jax.value_and_grad(loss))(params_w, mb)
+        axes = WK.worker_axes(params_w)
+        return jax.vmap(jax.value_and_grad(loss), in_axes=(axes, 0),
+                        out_axes=(0, axes))(params_w, mb)
 
     out_sh = (NamedSharding(mesh, P("worker")), wspec)
     with mesh:
@@ -126,8 +129,9 @@ def _train_base_cost(cfg, topo, mesh, W, zero):
     """One base-optimizer direction+update over the FULL stacked params."""
     base_opt = get_base_optimizer(topo.base_opt)
     aps = S.abstract_params(cfg)
-    wparams = jax.eval_shape(lambda p: _broadcast_workers(p, W), aps)
-    bstate = jax.eval_shape(lambda p: jax.vmap(base_opt.init)(p), wparams)
+    wparams = jax.eval_shape(lambda p: WK.broadcast_workers(p, W), aps)
+    bstate = jax.eval_shape(
+        lambda p: WK.broadcast_workers(base_opt.init(p), W), aps)
 
     wspec = shd.to_named(
         shd.param_pspecs(wparams, model=MODEL_PAR, zero=zero, worker_axis=True), mesh)
@@ -143,7 +147,9 @@ def _train_base_cost(cfg, topo, mesh, W, zero):
                 p, d)
             return new_p, new_bs
 
-        return jax.vmap(per_worker)(params_w, grads_w, bs_w)
+        axes = (WK.worker_axes(params_w), WK.worker_axes(bs_w))
+        return jax.vmap(per_worker, in_axes=(axes[0], *axes),
+                        out_axes=axes)(params_w, grads_w, bs_w)
 
     with mesh:
         lowered = jax.jit(
@@ -156,7 +162,7 @@ def _train_base_cost(cfg, topo, mesh, W, zero):
 def _train_global_cost(cfg, topo, mesh, W, zero, zero_global_buffers=False):
     """The paper's global step: all-reduce over workers + sign momentum + sync."""
     aps = S.abstract_params(cfg)
-    wparams = jax.eval_shape(lambda p: _broadcast_workers(p, W), aps)
+    wparams = jax.eval_shape(lambda p: WK.broadcast_workers(p, W), aps)
     m_sds = jax.eval_shape(
         lambda p: jax.tree.map(lambda l: jnp.zeros(l.shape, jnp.float32), p), aps)
 
@@ -172,10 +178,10 @@ def _train_global_cost(cfg, topo, mesh, W, zero, zero_global_buffers=False):
     dsm_cfg = DSMConfig(tau=topo.tau)
 
     def gstep(x0, m, params_w):
-        x_tau = jax.tree.map(lambda p: p.mean(axis=0), params_w)  # THE all-reduce
+        x_tau = WK.worker_mean(params_w)  # THE all-reduce
         new_x0, new_m = global_sign_momentum_step(
             x0, m, x_tau, jnp.float32(3e-4), dsm_cfg)
-        return new_x0, new_m, _broadcast_workers(new_x0, W)
+        return new_x0, new_m, WK.broadcast_workers(new_x0, W)
 
     with mesh:
         lowered = jax.jit(

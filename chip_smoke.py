@@ -198,6 +198,8 @@ def one_chip(clock) -> None:
 def four_chips(clock) -> None:
     import jax
 
+    from repro.core.workers import worker_axis
+
     check(len(jax.devices()) >= 4, f"--chips 4 needs 4 devices, "
           f"JAX has {len(jax.devices())}")
     hists = {}
@@ -206,10 +208,11 @@ def four_chips(clock) -> None:
         _, s, _, result = launch(["--device-parallel-local", *extra])
         check_training(s, result)
         state = result.pop("state")
-        for leaf in jax.tree.leaves(state.params):
+        for path, leaf in jax.tree_util.tree_flatten_with_path(state.params)[0]:
             check(len(leaf.sharding.device_set) == 4,
                   f"worker params span {len(leaf.sharding.device_set)} devices")
-            check(leaf.sharding.shard_shape(leaf.shape)[0] == W // 4,
+            ax = worker_axis(path)
+            check(leaf.sharding.shard_shape(leaf.shape)[ax] == W // 4,
                   "each chip must hold exactly one worker")
         x0_shards = [l.sharding.shard_shape(l.shape) != l.shape
                      for l in jax.tree.leaves(state.x0)]

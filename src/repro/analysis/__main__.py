@@ -2,7 +2,9 @@
 
   audit  — compile the dense / device-parallel / ZeRO-sharded outer steps
            and the bare local phase, parse their collectives, and check
-           them against the budgets derived from benchmarks/comm.py.
+           them against the budgets derived from benchmarks/comm.py; also
+           print each program's relayout report (XLA remat clones and
+           bytes of re-laid-out state, hlo_audit.relayout_report).
            Forces a multi-device host (``--devices``, default 8) BEFORE
            jax is imported so the mesh is not degenerate.
   lint   — run the RPR0xx rules over files/directories.
@@ -65,6 +67,8 @@ def _cmd_audit(args: argparse.Namespace) -> int:
                 status = "PASS (caught)" if not r.passed else \
                     "FAIL (planted collective NOT caught)"
             print(f"[{status}] {r.name:<32} {counts}")
+            print("         relayout: " + ", ".join(
+                f"{k}={v}" for k, v in r.relayout.items()))
             for v in r.violations:
                 print(f"         {v}")
         if degenerate and not args.allow_degenerate:

@@ -99,6 +99,64 @@ def parse_collectives(hlo_text: str) -> list[CollectiveOp]:
 
 
 # ---------------------------------------------------------------------------
+# Relayout: what the compiler does to the state beyond the program's math
+# ---------------------------------------------------------------------------
+
+# `%name = shape op(operands)`; the shape may be a tuple
+_INSTR_RE = re.compile(
+    r"^\s*(?:ROOT\s+)?%?(?P<name>[\w.\-]+)\s*=\s*"
+    r"(?P<shape>\(.*?\)|\S+)\s+(?P<op>[\w\-]+)\((?P<args>[^)]*)\)")
+_LAYOUT_RE = re.compile(r"\{(?P<order>[\d,]*)")
+# ops that hand an entry parameter's buffer on unchanged
+_PASS_THROUGH = ("bitcast", "copy-start", "copy-done")
+
+
+def relayout_report(hlo_text: str) -> dict:
+    """Two counts of a compiled module that the program's math never asks
+    for:
+
+    * ``remat_clones``: instructions that XLA's memory rematerialization
+      pass cloned (it names them ``<name>.remat<N>``) — values recomputed
+      because the program did not fit in memory as scheduled;
+    * ``state_copy_bytes`` (and ``state_copies``): bytes of the entry
+      computation's ``copy`` instructions that change the layout of an
+      entry parameter, reached directly or through a bitcast or an async
+      copy — state re-laid out at every call.
+    """
+    remat = set()
+    entry, params, copies = False, {}, []
+    for line in hlo_text.splitlines():
+        if line.startswith("ENTRY"):
+            entry = True
+            continue
+        if entry and line.startswith("}"):
+            entry = False
+        m = _INSTR_RE.match(line)
+        if not m:
+            continue
+        name, op = m.group("name"), m.group("op")
+        if ".remat" in name:
+            remat.add(name)
+        if not entry:
+            continue
+        operand = m.group("args").split(",")[0].strip().lstrip("%")
+        if op == "parameter":
+            params[name] = m.group("shape")
+        elif op in _PASS_THROUGH and operand in params:
+            params[name] = params[operand]
+        elif op == "copy" and operand in params:
+            copies.append((m.group("shape"), params[operand]))
+
+    def order(shape):
+        found = _LAYOUT_RE.search(shape.split("]", 1)[-1])
+        return found.group("order") if found else ""
+
+    moved = [shape for shape, src in copies if order(shape) != order(src)]
+    return {"remat_clones": len(remat), "state_copies": len(moved),
+            "state_copy_bytes": sum(_shape_bytes(s) for s in moved)}
+
+
+# ---------------------------------------------------------------------------
 # Budgets
 # ---------------------------------------------------------------------------
 
@@ -150,6 +208,7 @@ class AuditReport:
     budget: CollectiveBudget
     ops: list
     violations: list
+    relayout: dict = dataclasses.field(default_factory=dict)
 
     @property
     def passed(self) -> bool:
@@ -174,6 +233,7 @@ class AuditReport:
                                 if o.kind in self.budget.gather_class),
             "budget": dataclasses.asdict(self.budget),
             "violations": list(self.violations),
+            "relayout": dict(self.relayout),
             "ops": [dataclasses.asdict(o) for o in self.ops],
         }
 
@@ -210,7 +270,8 @@ def audit_text(hlo_text: str, budget: CollectiveBudget,
         viol.append(
             f"gather payload {gbytes} B exceeds the budget of "
             f"{budget.max_gather_bytes} B")
-    return AuditReport(name=name, budget=budget, ops=ops, violations=viol)
+    return AuditReport(name=name, budget=budget, ops=ops, violations=viol,
+                       relayout=relayout_report(hlo_text))
 
 
 def audit_jitted(fn, args: Sequence, budget: CollectiveBudget,
@@ -245,6 +306,7 @@ def standard_audit(n_workers: int = 4, tau: int = 2,
     from benchmarks.tables import NANO
     from repro.core import (DSMConfig, constant, dsm_init, get_base_optimizer,
                             make_dsm_step, make_local_phase)
+    from repro.core.workers import worker_pspecs
     from repro.data.pipeline import MarkovCorpus, dsm_batches
     from repro.launch.mesh import host_training_mesh
     from repro.models import transformer as T
@@ -315,7 +377,7 @@ def standard_audit(n_workers: int = 4, tau: int = 2,
             return jax.shard_map(
                 lambda t: jax.tree.map(
                     lambda x: jax.lax.psum(x, "worker"), t),
-                mesh=mesh, in_specs=P("worker"), out_specs=P(),
+                mesh=mesh, in_specs=(worker_pspecs(tree),), out_specs=P(),
                 check_vma=False)(tree)
 
         def planted(state, batch):

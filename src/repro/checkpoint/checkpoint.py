@@ -12,6 +12,11 @@ the single-file primitives, the rotated-checkpoint manager
 ``latest`` pointer and the last ``keep`` complete checkpoints in a
 directory, which is what the trainer's ``checkpoint_every`` / ``resume``
 settings drive (see docs/fault_tolerance.md).
+
+Each checkpoint records where per-worker state holds its worker axis
+(``repro.layout.WORKER_LAYOUT``).  A checkpoint written before layer-stacked
+leaves moved their worker axis to second place holds them as ``(W, L, ...)``;
+restoring one raises an error that names the leaf (docs/local_phase.md).
 """
 
 from __future__ import annotations
@@ -24,6 +29,8 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from repro.layout import STACK_KEY, WORKER_LAYOUT, is_stacked
 
 PyTree = Any
 _BF16_TAG = "__bf16__"
@@ -66,7 +73,7 @@ def save(path: str, tree: PyTree, step: int = 0,
     stored in the json sidecar, readable via :func:`load_meta`.
     """
     flat = jax.tree_util.tree_flatten_with_path(tree)[0]
-    arrays, meta = {}, {"step": step, "keys": []}
+    arrays, meta = {}, {"step": step, "keys": [], "worker_layout": WORKER_LAYOUT}
     if extra is not None:
         meta["extra"] = extra
     for i, (p, leaf) in enumerate(flat):
@@ -93,18 +100,32 @@ def load_meta(path: str) -> dict:
         return json.load(f)
 
 
+def _check_worker_layout(pstr: str, path, got: tuple, want: tuple) -> None:
+    """Refuse a layer-stacked leaf of a checkpoint that predates the
+    ``(L, W, ...)`` layout where it reads as a worker-first ``(W, L, ...)``
+    one: its shape differs from ``want`` by the order of the first two
+    axes.  A leaf whose shape agrees is restored: most have no worker axis
+    (``x0``, params alone), and one with ``W == L`` cannot be told apart."""
+    if (is_stacked(path) and got != want
+            and got == (want[1], want[0]) + want[2:]):
+        raise ValueError(
+            f"{pstr}: the checkpoint was written before per-worker leaves "
+            f"under '{STACK_KEY}' moved their worker axis from first to "
+            f"second place; it holds {got} where this layout needs {want} "
+            "(docs/local_phase.md)")
+
+
 def restore(path: str, like: PyTree) -> tuple[PyTree, int]:
     """Restore into the structure of ``like`` (shape- AND dtype-checked:
     a f32/i32 layout drift raises instead of silently casting)."""
     meta = load_meta(path)
-    flat, treedef = jax.tree_util.tree_flatten(like)
-    flat_paths = [
-        _path_str(p) for p, _ in jax.tree_util.tree_flatten_with_path(like)[0]
-    ]
+    stacked_worker_first = meta.get("worker_layout") != WORKER_LAYOUT
+    flat_with_paths, treedef = jax.tree_util.tree_flatten_with_path(like)
     saved = {k: i for i, (k, _) in enumerate(meta["keys"])}
     out = []
     with np.load(path + ".npz") as data:
-        for leaf, pstr in zip(flat, flat_paths):
+        for path_keys, leaf in flat_with_paths:
+            pstr = _path_str(path_keys)
             if pstr not in saved:
                 raise KeyError(f"checkpoint missing leaf {pstr}")
             i = saved[pstr]
@@ -119,6 +140,9 @@ def restore(path: str, like: PyTree) -> tuple[PyTree, int]:
                     f"expected {want}")
             if got == _BF16_TAG:
                 arr = arr.view(jnp.bfloat16)
+            if stacked_worker_first:
+                _check_worker_layout(pstr, path_keys, tuple(arr.shape),
+                                     tuple(np.shape(leaf)))
             if tuple(arr.shape) != tuple(np.shape(leaf)):
                 raise ValueError(
                     f"shape mismatch for {pstr}: {arr.shape} vs {np.shape(leaf)}")

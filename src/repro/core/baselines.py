@@ -23,14 +23,15 @@ from typing import Any, Callable, NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.core import workers as WK
 from repro.core.base_opt import BaseOptimizer, adamw
-from repro.core.dsm import _broadcast_workers, make_local_phase, randomized_sign_pm
+from repro.core.dsm import make_local_phase, randomized_sign_pm
 
 PyTree = Any
 
 
 class LocalMethodState(NamedTuple):
-    params: PyTree      # (W, *shape) per-worker
+    params: PyTree      # per-worker (repro.core.workers layout)
     x0: PyTree          # global model buffer
     aux: PyTree         # method-specific global state (momentum etc.)
     base_state: PyTree  # per-worker base-opt state
@@ -63,12 +64,11 @@ def make_local_step_method(
     )
 
     def init(params: PyTree, n_workers: int) -> LocalMethodState:
-        wp = _broadcast_workers(params, n_workers)
         state = LocalMethodState(
-            params=wp,
+            params=WK.broadcast_workers(params, n_workers),
             x0=params,
             aux=init_aux(params),
-            base_state=jax.vmap(base_opt.init)(wp),
+            base_state=WK.broadcast_workers(base_opt.init(params), n_workers),
             t=jnp.zeros((), jnp.int32),
             inner=jnp.zeros((), jnp.int32),
         )
@@ -76,13 +76,8 @@ def make_local_step_method(
             from repro.distributed import zero as Z
 
             state = state._replace(
-                params=jax.tree.map(
-                    lambda x: jax.device_put(x, Z.worker_sharding(mesh)),
-                    state.params),
-                base_state=jax.tree.map(
-                    lambda x: jax.device_put(x, Z.worker_sharding(mesh))
-                    if getattr(x, "ndim", 0) >= 1 else x,
-                    state.base_state),
+                params=Z.put_workers(state.params, mesh),
+                base_state=Z.put_workers(state.base_state, mesh),
             )
         return state
 
@@ -93,11 +88,10 @@ def make_local_step_method(
             state.params, state.base_state, batch, gamma, state.inner
         )
 
-        x_tau_mean = jax.tree.map(lambda p: p.mean(axis=0), params_w)  # all-reduce
+        x_tau_mean = WK.worker_mean(params_w)  # all-reduce
         new_x0, new_aux = global_update(state.x0, state.aux, x_tau_mean, gamma, state.t)
 
-        n_workers = jax.tree.leaves(state.params)[0].shape[0]
-        new_params = _broadcast_workers(new_x0, n_workers)
+        new_params = WK.broadcast_workers(new_x0, WK.n_workers_of(state.params))
         if mesh is not None:
             from repro.distributed import zero as Z
 
@@ -288,7 +282,7 @@ def make_perstep_dp_step(loss_fn, base_opt: BaseOptimizer, tau: int, schedule):
 class MVState(NamedTuple):
     x: PyTree
     x_prev: PyTree
-    m: PyTree           # per-worker momentum (W, *shape)
+    m: PyTree           # per-worker momentum (repro.core.workers layout)
     t: jnp.ndarray
 
 
@@ -304,7 +298,7 @@ def make_mv_signsgd_step(
         return MVState(
             x=params,
             x_prev=jax.tree.map(jnp.copy, params),  # own buffers: state is donated
-            m=_broadcast_workers(
+            m=WK.broadcast_workers(
                 jax.tree.map(lambda p: jnp.zeros_like(p, jnp.float32), params), n_workers
             ),
             t=jnp.zeros((), jnp.int32),
@@ -313,8 +307,8 @@ def make_mv_signsgd_step(
     def outer_step(state: MVState, batch, rng: jax.Array):
         # y_t = x_t + alpha (x_t - x_{t-1})
         y = jax.tree.map(lambda a, b: a + alpha * (a - b), state.x, state.x_prev)
-        n_workers = jax.tree.leaves(state.m)[0].shape[0]
-        y_w = _broadcast_workers(y, n_workers)
+        y_w = WK.broadcast_workers(y, WK.n_workers_of(state.m))
+        axes = WK.worker_axes(y_w)
 
         def one_local(carry, microbatch):
             z, k = carry
@@ -323,7 +317,8 @@ def make_mv_signsgd_step(
                 loss, g = grad_fn(p, mb)
                 return jax.tree.map(lambda x, gg: x - gamma * gg, p, g), loss
 
-            new_z, losses = jax.vmap(per_worker)(z, microbatch)
+            new_z, losses = jax.vmap(per_worker, in_axes=(axes, 0),
+                                     out_axes=(axes, 0))(z, microbatch)
             return (new_z, k + 1), losses.mean()
 
         mb_scan = jax.tree.map(lambda x: jnp.swapaxes(x, 0, 1)[: tau], batch)
@@ -333,19 +328,21 @@ def make_mv_signsgd_step(
 
         # local momentum from a fresh gradient at y^{(i)} = z_tau^{(i)}
         last_mb = jax.tree.map(lambda x: x[:, -1], batch)
-        _, g_last = jax.vmap(lambda p, mb: grad_fn(p, mb))(z_tau, last_mb)
+        _, g_last = jax.vmap(grad_fn, in_axes=(axes, 0),
+                             out_axes=(0, axes))(z_tau, last_mb)
         new_m = jax.tree.map(
             lambda m, g: beta * m + (1 - beta) * _f32(g), state.m, g_last
         )
 
         # randomized sign per worker, sum, majority vote
-        leaves, treedef = jax.tree.flatten(new_m)
+        leaves, treedef = jax.tree_util.tree_flatten_with_path(new_m)
         keys = jax.random.split(rng, len(leaves))
         votes = [
-            jax.vmap(lambda mm, kk: randomized_sign_pm(mm, kk, bound))(
-                leaf, jax.random.split(key, leaf.shape[0])
+            jax.vmap(lambda mm, kk: randomized_sign_pm(mm, kk, bound),
+                     in_axes=(WK.worker_axis(path), 0))(
+                leaf, jax.random.split(key, leaf.shape[WK.worker_axis(path)])
             ).sum(axis=0)
-            for leaf, key in zip(leaves, keys)
+            for (path, leaf), key in zip(leaves, keys)
         ]
         vote_tree = jax.tree.unflatten(treedef, votes)
         new_x = jax.tree.map(

@@ -12,8 +12,10 @@ Structure (one *outer* step t):
          m_{t+1}   = beta2 * m_t + (1-beta2) * Delta_t          (eq. 8)
   4. broadcast x_{t+1,0} back to all workers.
 
-Workers are represented by a leading axis ``W`` on params / optimizer state /
-batches.  Under the production mesh this axis is sharded over the
+Workers are represented by an axis ``W`` on params / optimizer state /
+batches: leading on batches and on most leaves, second on the layer-stacked
+leaves the model scans (``repro.core.workers``).  Under the production mesh
+this axis is sharded over the
 ``("pod","data")`` axes, so step 1 emits **no inter-worker collectives**
 (everything is elementwise in W) and step 2 lowers to a single all-reduce
 over (pod, data) — the tau-amortized communication the paper is about.
@@ -34,6 +36,7 @@ from typing import Any, Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+from repro.core import workers as WK
 from repro.core.base_opt import BaseOptimizer
 from repro.obs import metrics as OM
 
@@ -98,18 +101,14 @@ class DSMConfig:
 
 
 class DSMState(NamedTuple):
-    params: PyTree       # per-worker params, leaves (W, *shape)
+    # per-worker leaves are (W, *shape), or (L, W, ...) when layer-stacked
+    # (repro.core.workers)
+    params: PyTree       # per-worker params
     x0: PyTree           # global model buffer x_{t,0}, leaves (*shape)
     m: PyTree            # global sign momentum m_t, leaves (*shape)
-    base_state: PyTree   # per-worker base-opt state, leaves (W, ...)
+    base_state: PyTree   # per-worker base-opt state
     t: jnp.ndarray       # outer step counter
     inner: jnp.ndarray   # total local-step counter (base-opt bias correction)
-
-
-def _broadcast_workers(x0: PyTree, n_workers: int) -> PyTree:
-    return jax.tree.map(
-        lambda x: jnp.broadcast_to(x[None], (n_workers,) + x.shape), x0
-    )
 
 
 # ---------------------------------------------------------------------------
@@ -124,12 +123,12 @@ def _broadcast_workers(x0: PyTree, n_workers: int) -> PyTree:
 
 def worker_finite_mask(params_w: PyTree) -> jnp.ndarray:
     """``(W,)`` bool: worker i's contribution is finite in EVERY leaf."""
-    leaves = [l for l in jax.tree.leaves(params_w)
-              if jnp.issubdtype(l.dtype, jnp.floating)]
-    n_workers = jax.tree.leaves(params_w)[0].shape[0]
-    ok = jnp.ones((n_workers,), bool)
-    for l in leaves:
-        ok = ok & jnp.isfinite(l).reshape(l.shape[0], -1).all(axis=1)
+    ok = jnp.ones((WK.n_workers_of(params_w),), bool)
+    for path, l in jax.tree_util.tree_flatten_with_path(params_w)[0]:
+        if jnp.issubdtype(l.dtype, jnp.floating):
+            ax = WK.worker_axis(path)
+            ok = ok & jnp.isfinite(l).all(
+                axis=tuple(i for i in range(l.ndim) if i != ax))
     return ok
 
 
@@ -139,12 +138,13 @@ def masked_worker_mean(params_w: PyTree, weights: jnp.ndarray) -> PyTree:
     vector yields 0 (the caller must apply skip-round semantics)."""
     wsum = jnp.maximum(weights.astype(jnp.float32).sum(), 1.0)
 
-    def leaf(p):
-        w = weights.astype(p.dtype).reshape((p.shape[0],) + (1,) * (p.ndim - 1))
+    def leaf(path, p):
+        w = WK.along_workers(weights.astype(p.dtype), path, p)
         contrib = jnp.where(w > 0, p, jnp.zeros((), p.dtype))
-        return (w * contrib).sum(axis=0) / wsum.astype(p.dtype)
+        return ((w * contrib).sum(axis=WK.worker_axis(path))
+                / wsum.astype(p.dtype))
 
-    return jax.tree.map(leaf, params_w)
+    return jax.tree_util.tree_map_with_path(leaf, params_w)
 
 
 def _contribution_weights(contrib: PyTree, cfg: "DSMConfig",
@@ -177,13 +177,12 @@ def dsm_init(
     device-parallel local phase without ``zero_sharded`` keeps x0 / m
     replicated (``global_sharded=False``).
     """
-    worker_params = _broadcast_workers(params, n_workers)
-    base_state = jax.vmap(base_opt.init)(worker_params)
     state = DSMState(
-        params=worker_params,
+        params=WK.broadcast_workers(params, n_workers),
         x0=params,
         m=jax.tree.map(lambda p: jnp.zeros_like(p, dtype=momentum_dtype), params),
-        base_state=base_state,
+        # every worker starts from the same params, so from the same state
+        base_state=WK.broadcast_workers(base_opt.init(params), n_workers),
         t=jnp.zeros((), jnp.int32),
         inner=jnp.zeros((), jnp.int32),
     )
@@ -261,11 +260,12 @@ def global_sign_momentum_step(
 #   * vmapped (default): the worker axis W lives on one device and is mapped
 #     with jax.vmap — a *simulation* of n workers (replicated compute).
 #   * device-parallel (``device_parallel=True`` + mesh): the same body runs
-#     under shard_map with every per-worker input sharded P("worker"), so
-#     each device executes only its own worker block.  The body contains no
-#     psum/ppermute and never reads across the worker axis, so the compiled
-#     local phase emits ZERO inter-worker collectives by construction — the
-#     paper's premise that tau local steps are communication-free.
+#     under shard_map with every per-worker input sharded over "worker" on
+#     its worker axis, so each device executes only its own worker block.
+#     The body contains no psum/ppermute and never reads across the worker
+#     axis, so the compiled local phase emits ZERO inter-worker collectives
+#     by construction — the paper's premise that tau local steps are
+#     communication-free.
 #     Per-worker losses are returned unreduced (tau, W); the caller averages
 #     them *outside* the local phase, where a collective is expected anyway.
 # ---------------------------------------------------------------------------
@@ -324,9 +324,10 @@ def make_local_phase(
                     )
                 return new_p, new_bs, loss
 
-            new_params, new_base, losses = jax.vmap(per_worker)(
-                params, base_state, microbatch
-            )
+            axes = (WK.worker_axes(params), WK.worker_axes(base_state))
+            new_params, new_base, losses = jax.vmap(
+                per_worker, in_axes=(*axes, 0), out_axes=(*axes, 0),
+            )(params, base_state, microbatch)
             return (new_params, new_base, k + 1), losses  # (W_block,)
 
         # scan over the tau microbatches: batch leaves (W, tau, ...) -> (tau, W, ...)
@@ -347,25 +348,24 @@ def make_local_phase(
 
     from jax.sharding import PartitionSpec as P
 
-    wspec = P("worker")
     n_worker_devices = dict(zip(mesh.axis_names, mesh.devices.shape))["worker"]
-    sharded_block = jax.shard_map(
-        local_phase_block,
-        mesh=mesh,
-        in_specs=(wspec, wspec, wspec, P(), P()),
-        out_specs=(wspec, wspec, P(None, "worker")),
-        check_vma=False,
-    )
 
     def local_phase(params_w, base_state_w, batch, gamma, inner0):
-        n_workers = jax.tree.leaves(params_w)[0].shape[0]
+        n_workers = WK.n_workers_of(params_w)
         if n_workers % n_worker_devices:
             raise ValueError(
                 f"n_workers={n_workers} must be a multiple of the mesh's "
                 f"worker axis ({n_worker_devices}) for the device-parallel "
                 "local phase"
             )
-        return sharded_block(params_w, base_state_w, batch, gamma, inner0)
+        specs = (WK.worker_pspecs(params_w), WK.worker_pspecs(base_state_w))
+        return jax.shard_map(
+            local_phase_block,
+            mesh=mesh,
+            in_specs=(*specs, P("worker"), P(), P()),
+            out_specs=(*specs, P(None, "worker")),
+            check_vma=False,
+        )(params_w, base_state_w, batch, gamma, inner0)
 
     return local_phase
 
@@ -413,7 +413,7 @@ def make_dsm_step(
     def outer_step(state: DSMState, batch, rng: Optional[jax.Array] = None,
                    faults=None):
         gamma = schedule(state.t)
-        n_workers = jax.tree.leaves(state.params)[0].shape[0]
+        n_workers = WK.n_workers_of(state.params)
 
         with jax.named_scope("dsm_local_phase"):
             params_w, base_state_w, losses = local_phase(
@@ -446,7 +446,7 @@ def make_dsm_step(
             else:
                 # --- line 7: THE all-reduce over workers (once per tau local steps) ---
                 if weights is None:
-                    x_tau = jax.tree.map(lambda p: p.mean(axis=0), contrib)
+                    x_tau = WK.worker_mean(contrib)
                 else:
                     x_tau = masked_worker_mean(contrib, weights)
                 if mesh is not None:
@@ -474,7 +474,7 @@ def make_dsm_step(
                                  new_m, state.m)
 
         # --- line 11: synchronize workers (the all-gather when sharded) ---
-        new_params = _broadcast_workers(new_x0, n_workers)
+        new_params = WK.broadcast_workers(new_x0, n_workers)
         if mesh is not None:
             from repro.distributed import zero as Z
 

@@ -22,6 +22,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.layout import is_stacked
+
 PyTree = Any
 
 # leaf-name -> which dim (from the end, ignoring stacked prefixes) is the
@@ -83,7 +85,8 @@ def param_pspecs(
 ) -> PyTree:
     """PartitionSpecs for a parameter pytree.
 
-    ``worker_axis``: leaves carry a leading per-worker dim -> "worker".
+    ``worker_axis``: leaves carry a per-worker dim -> "worker": the leading
+    dim, or the second of a layer-stacked leaf (repro.core.workers).
     ``zero_axes``: mesh axes for the FSDP dim (e.g. ("zero",) or
     ("worker","zero") for fully-sharded global buffers).
     """
@@ -93,15 +96,12 @@ def param_pspecs(
         shape = leaf.shape
         name = _leaf_name(path)
         spec = [None] * len(shape)
-        i0 = 0
+        if worker_axis and len(shape) == 0:
+            return P()
+        # stacked-layer dim (scan) first: leave unsharded
+        i0 = 1 if is_stacked(path) and len(shape) > 0 else 0
         if worker_axis:
-            if len(shape) == 0:
-                return P()
-            spec[0] = "worker"
-            i0 = 1
-        # stacked-layer dim (scan) right after worker dim: leave unsharded
-        path_str = "/".join(str(getattr(e, "key", e)) for e in path)
-        if "blocks" in path_str and len(shape) > i0:
+            spec[i0] = "worker"
             i0 += 1
         taken = set()
         md = (
@@ -165,8 +165,7 @@ def cache_pspecs(cache: PyTree, data: int, model: int, stacked_hint: bool = True
     def spec_for(path, leaf):
         shape = leaf.shape
         spec = [None] * len(shape)
-        path_str = "/".join(str(getattr(e, "key", e)) for e in path)
-        i0 = 1 if ("blocks" in path_str and len(shape) > 1) else 0
+        i0 = 1 if (is_stacked(path) and len(shape) > 1) else 0
         taken = set()
         # data axis: prefer batch dim (i0), else next dims
         dd = None

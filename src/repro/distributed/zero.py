@@ -54,6 +54,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from repro.core import workers as WK
 from repro.distributed.sharding import param_pspecs
 from repro.kernels.dsm_update import LANES, dsm_update_2d
 
@@ -86,18 +87,21 @@ def constrain_global(tree: PyTree, mesh: Mesh) -> PyTree:
                         global_buffer_shardings(tree, mesh))
 
 
-def worker_sharding(mesh: Mesh) -> NamedSharding:
-    """Per-worker leaves (W, ...): shard the leading worker dim only."""
-    return NamedSharding(mesh, P("worker"))
+def worker_shardings(tree: PyTree, mesh: Mesh) -> PyTree:
+    """Per-worker leaves: shard each leaf's worker axis only
+    (repro.core.workers)."""
+    return jax.tree.map(lambda s: NamedSharding(mesh, s),
+                        WK.worker_pspecs(tree),
+                        is_leaf=lambda x: isinstance(x, P))
 
 
 def constrain_workers(tree: PyTree, mesh: Mesh) -> PyTree:
-    ws = worker_sharding(mesh)
-    return jax.tree.map(
-        lambda x: jax.lax.with_sharding_constraint(x, ws)
-        if getattr(x, "ndim", 0) >= 1 else x,
-        tree,
-    )
+    return jax.tree.map(jax.lax.with_sharding_constraint, tree,
+                        worker_shardings(tree, mesh))
+
+
+def put_workers(tree: PyTree, mesh: Mesh) -> PyTree:
+    return jax.tree.map(jax.device_put, tree, worker_shardings(tree, mesh))
 
 
 def shard_dsm_state(state, mesh: Mesh, global_sharded: bool = True):
@@ -105,12 +109,7 @@ def shard_dsm_state(state, mesh: Mesh, global_sharded: bool = True):
     state sharded over worker; x0 / m in the ZeRO (worker, zero) layout when
     ``global_sharded``, replicated otherwise (device-parallel local phase
     with a replicated global step)."""
-    ws = worker_sharding(mesh)
     rep = NamedSharding(mesh, P())
-
-    def put_worker(x):
-        return jax.device_put(x, ws if getattr(x, "ndim", 0) >= 1 else rep)
-
     if global_sharded:
         x0_sh = global_buffer_shardings(state.x0, mesh)
         m_sh = global_buffer_shardings(state.m, mesh)
@@ -119,10 +118,10 @@ def shard_dsm_state(state, mesh: Mesh, global_sharded: bool = True):
         m_sh = jax.tree.map(lambda _: rep, state.m)
 
     return type(state)(
-        params=jax.tree.map(put_worker, state.params),
+        params=put_workers(state.params, mesh),
         x0=jax.tree.map(jax.device_put, state.x0, x0_sh),
         m=jax.tree.map(jax.device_put, state.m, m_sh),
-        base_state=jax.tree.map(put_worker, state.base_state),
+        base_state=put_workers(state.base_state, mesh),
         t=jax.device_put(state.t, rep),
         inner=jax.device_put(state.inner, rep),
     )
@@ -136,7 +135,7 @@ def _scattered_worker_mean(params_w, mesh, weights=None):
     """x_tau = mean_i x^{(i)}_{t,tau}, reduced directly into the
     (worker, zero) shard layout — the reduce-scatter of the outer step.
 
-    The per-worker iterates are pinned to their P("worker") layout first, so
+    The per-worker iterates are pinned to their worker-sharded layout first, so
     when the local phase ran device-parallel the partitioner consumes the
     already-worker-sharded x_tau in place (worker-axis reduction straight
     into shards) instead of gathering the W copies to every rank and
@@ -147,7 +146,7 @@ def _scattered_worker_mean(params_w, mesh, weights=None):
     still elementwise in W, so the reduce-scatter structure is unchanged."""
     params_w = constrain_workers(params_w, mesh)
     if weights is None:
-        x_tau = jax.tree.map(lambda p: p.mean(axis=0), params_w)
+        x_tau = WK.worker_mean(params_w)
     else:
         from repro.core.dsm import masked_worker_mean
 

@@ -23,6 +23,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.layout import STACK_KEY
 from repro.models import layers as L
 
 PyTree = Any
@@ -79,7 +80,7 @@ def _init_stack(key, pattern, n_blocks, n_rem, cfg) -> PyTree:
     rem = tuple(
         _init_block(jax.random.fold_in(kr, i), pattern[i], cfg) for i in range(n_rem)
     )
-    return {"blocks": blocks, "rem": rem}
+    return {STACK_KEY: blocks, "rem": rem}
 
 
 def init_params(key, cfg) -> PyTree:
@@ -214,16 +215,16 @@ def _run_stack(stack, pattern, x, positions, cfg, enc_out=None, remat=True,
     else:
         body_fn = body
     aux0 = jnp.zeros((), F32)
-    if stack["blocks"]:
+    if stack[STACK_KEY]:
         if unroll:
-            nb = jax.tree.leaves(stack["blocks"])[0].shape[0]
+            nb = jax.tree.leaves(stack[STACK_KEY])[0].shape[0]
             carry = (x, aux0)
             for i in range(nb):
-                bp = jax.tree.map(lambda a: a[i], stack["blocks"])
+                bp = jax.tree.map(lambda a: a[i], stack[STACK_KEY])
                 carry, _ = body_fn(carry, bp)
             x, aux = carry
         else:
-            (x, aux), _ = jax.lax.scan(body_fn, (x, aux0), stack["blocks"])
+            (x, aux), _ = jax.lax.scan(body_fn, (x, aux0), stack[STACK_KEY])
     else:
         aux = aux0
     for i, p in enumerate(stack["rem"]):
@@ -367,7 +368,7 @@ def init_cache(cfg, batch: int, max_len: int, dtype=None) -> PyTree:
         _init_block_cache(cfg.pattern[i], cfg, batch, max_len, dtype)
         for i in range(cfg.n_rem_layers)
     )
-    return {"blocks": blocks, "rem": rem}
+    return {STACK_KEY: blocks, "rem": rem}
 
 
 def _decode_block(p, kind, cache, x, pos, cfg, max_len):
@@ -434,21 +435,21 @@ def decode_step(params, cache, tokens, pos, cfg, unroll: bool = False):
             )
         return x, new_caches
 
-    new_cache = {"blocks": {}, "rem": []}
-    if params["decoder"]["blocks"]:
+    new_cache = {STACK_KEY: {}, "rem": []}
+    if params["decoder"][STACK_KEY]:
         if unroll:
-            nb = jax.tree.leaves(params["decoder"]["blocks"])[0].shape[0]
+            nb = jax.tree.leaves(params["decoder"][STACK_KEY])[0].shape[0]
             ys = []
             for i in range(nb):
-                bp = jax.tree.map(lambda a: a[i], params["decoder"]["blocks"])
-                bc = jax.tree.map(lambda a: a[i], cache["blocks"])
+                bp = jax.tree.map(lambda a: a[i], params["decoder"][STACK_KEY])
+                bc = jax.tree.map(lambda a: a[i], cache[STACK_KEY])
                 x, nc = body(x, (bp, bc))
                 ys.append(nc)
-            new_cache["blocks"] = jax.tree.map(
+            new_cache[STACK_KEY] = jax.tree.map(
                 lambda *xs: jnp.stack(xs), *ys) if ys else {}
         else:
-            x, new_cache["blocks"] = jax.lax.scan(
-                body, x, (params["decoder"]["blocks"], cache["blocks"])
+            x, new_cache[STACK_KEY] = jax.lax.scan(
+                body, x, (params["decoder"][STACK_KEY], cache[STACK_KEY])
             )
     for i, p in enumerate(params["decoder"]["rem"]):
         x, nc = _decode_block(p, cfg.pattern[i], cache["rem"][i], x, pos, cfg, max_len)
@@ -549,18 +550,18 @@ def prefill(params, batch, cfg, remat: bool = True, unroll: bool = False):
         return x, caches
 
     body_fn = jax.checkpoint(body) if remat else body
-    cache = {"blocks": {}, "rem": []}
-    if params["decoder"]["blocks"]:
+    cache = {STACK_KEY: {}, "rem": []}
+    if params["decoder"][STACK_KEY]:
         if unroll:
-            nb = jax.tree.leaves(params["decoder"]["blocks"])[0].shape[0]
+            nb = jax.tree.leaves(params["decoder"][STACK_KEY])[0].shape[0]
             ys = []
             for i in range(nb):
-                bp = jax.tree.map(lambda a: a[i], params["decoder"]["blocks"])
+                bp = jax.tree.map(lambda a: a[i], params["decoder"][STACK_KEY])
                 x, c = body_fn(x, bp)
                 ys.append(c)
-            cache["blocks"] = jax.tree.map(lambda *xs: jnp.stack(xs), *ys) if ys else {}
+            cache[STACK_KEY] = jax.tree.map(lambda *xs: jnp.stack(xs), *ys) if ys else {}
         else:
-            x, cache["blocks"] = jax.lax.scan(body_fn, x, params["decoder"]["blocks"])
+            x, cache[STACK_KEY] = jax.lax.scan(body_fn, x, params["decoder"][STACK_KEY])
     for i, p in enumerate(params["decoder"]["rem"]):
         x, entry = _prefill_block_cache(p, cfg.pattern[i], x, positions, cfg, enc_out)
         cache["rem"].append(entry)

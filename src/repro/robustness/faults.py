@@ -32,6 +32,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.core import workers as WK
+
 PyTree = object
 
 
@@ -139,11 +141,11 @@ def apply_faults(params_w: PyTree, x0: PyTree, faults: FaultRound) -> PyTree:
     mask in the masked worker mean), since a real dropout delivers nothing.
     """
 
-    def leaf(p, g):
-        shape = (p.shape[0],) + (1,) * (p.ndim - 1)
-        stale = faults.stale.reshape(shape)
-        corrupt = faults.corrupt.reshape(shape)
-        out = jnp.where(stale, g[None].astype(p.dtype), p)
+    def leaf(path, p, g):
+        stale = WK.along_workers(faults.stale, path, p)
+        corrupt = WK.along_workers(faults.corrupt, path, p)
+        g = jnp.expand_dims(g, WK.worker_axis(path)).astype(p.dtype)
+        out = jnp.where(stale, g, p)
         return jnp.where(corrupt, jnp.asarray(jnp.nan, p.dtype), out)
 
-    return jax.tree.map(leaf, params_w, x0)
+    return jax.tree_util.tree_map_with_path(leaf, params_w, x0)

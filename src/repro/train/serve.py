@@ -97,12 +97,13 @@ def _splice_cache(big, small, cfg, prompt_len: int):
             for name in big_e
         }
 
-    out = {"blocks": {}, "rem": []}
+    stack = T.STACK_KEY
+    out = {stack: {}, "rem": []}
     for j, kind in enumerate(cfg.pattern):
         keyn = f"p{j}"
-        if keyn in big["blocks"]:
-            out["blocks"][keyn] = splice_entry(
-                kind, big["blocks"][keyn], small["blocks"][keyn])
+        if keyn in big[stack]:
+            out[stack][keyn] = splice_entry(
+                kind, big[stack][keyn], small[stack][keyn])
     for i in range(len(big["rem"])):
         out["rem"].append(
             splice_entry(cfg.pattern[i], big["rem"][i], small["rem"][i]))

@@ -143,12 +143,14 @@ def test_sharding_rules_divisible(arch_id):
     """Every sharded dim must divide by its mesh-axis product (16x16 mesh)."""
     from jax.sharding import PartitionSpec as P
 
+    from repro.core.workers import broadcast_workers
+
     mod = load_arch(arch_id)
     aps = S.abstract_params(mod.FULL)
     W = mod.TOPO.n_workers_single
     zero = max(16 // W, 1)
-    wparams = jax.tree.map(
-        lambda l: jax.ShapeDtypeStruct((W,) + l.shape, l.dtype), aps)
+    # per-worker params in the state's layout (worker second when stacked)
+    wparams = jax.eval_shape(lambda p: broadcast_workers(p, W), aps)
     specs = shd.param_pspecs(wparams, model=16, zero=zero, worker_axis=True)
     sizes = {"worker": W, "zero": zero, "model": 16}
 

@@ -4,7 +4,9 @@ The TPU compiler refuses what interpret mode and the CPU backend accept: a
 Pallas tile the chip cannot hold, a step that does not fit its HBM.  These
 tests compile the fused DSM kernel at GPT-2 small slab shapes and whole DSM
 outer steps at GPT-2 small and medium widths for one v5e chip, from shapes
-alone.
+alone.  The whole steps also read ``hlo_audit.relayout_report``: with the
+worker axis second in layer-stacked state (repro.core.workers) the tau
+loop re-lays out no state, and XLA recomputes nothing for want of memory.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, so describing it while the
@@ -100,6 +102,50 @@ def _fits_one_v5e(compiled, state):
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < V5E_HBM
 
 
+def _no_relayout(hlo):
+    """No XLA rematerialization clone and no re-laid-out state leaf."""
+    from repro.analysis.hlo_audit import relayout_report
+
+    report = relayout_report(hlo)
+    assert report["remat_clones"] == 0, report
+    assert report["state_copy_bytes"] == 0, report
+
+
+@pytest.mark.parametrize("worker_axis,relaid_out", [(0, 1), (1, 0)],
+                         ids=["worker_first", "worker_second"])
+def test_relayout_report_reads_the_compilers_copies(one_chip, worker_axis,
+                                                    relaid_out):
+    """The counter on the compiler's own text: a tau loop of vmapped
+    gradient steps through a layer scan, state donated.  With the worker
+    first the compiler re-lays out the (W, L, D, D) state at the entry, as
+    the DSM step did before the worker axis moved; with it second it does
+    not."""
+    from repro.analysis.hlo_audit import relayout_report
+
+    n_workers, n_layers, d, b, tau = 2, 4, 256, 16, 3
+
+    def per_worker(w, x):
+        h, _ = jax.lax.scan(lambda h, wl: (jnp.tanh(h @ wl), None), x, w)
+        return (h ** 2).sum()
+
+    grad = jax.vmap(jax.grad(per_worker), in_axes=(worker_axis, 0),
+                    out_axes=worker_axis)
+
+    def step(w, xs):
+        return jax.lax.scan(lambda w, x: (w - 0.1 * grad(w, x), None),
+                            w, xs)[0]
+
+    shape = [n_layers, d, d]
+    shape.insert(worker_axis, n_workers)
+    w = jax.ShapeDtypeStruct(tuple(shape), jnp.float32, sharding=one_chip)
+    xs = jax.ShapeDtypeStruct((tau, n_workers, b, d), jnp.float32,
+                              sharding=one_chip)
+    compiled = jax.jit(step, donate_argnums=0).lower(w, xs).compile()
+    report = relayout_report(compiled.as_text())
+    assert report["state_copies"] == relaid_out, report
+    assert report["state_copy_bytes"] == relaid_out * 4 * w.size, report
+
+
 def _kernel_path_in_attention(hlo, score_dims=r"[\d,]*1024,1024"):
     """The fused attention kernel's calls keep the attention and remat
     scopes, and no (S, S) score convolution (output dims ``score_dims``)
@@ -123,20 +169,23 @@ def test_gpt2_small_dsm_step_fits_one_v5e(one_chip):
 def test_gpt2_small_dsm_step_with_flash_attention_fits_one_v5e(one_chip,
                                                                 monkeypatch):
     """All 12 layers with the fused attention kernel, as the step takes it
-    on a TPU backend: Mosaic accepts its tiles and the step fits."""
+    on a TPU backend: Mosaic accepts its tiles, the step fits, and the state
+    is not re-laid out."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     compiled, state = _compile_dsm_step(one_chip, "gpt2_small", n_workers=4,
                                         n_layers=12)
     _fits_one_v5e(compiled, state)
     _kernel_path_in_attention(compiled.as_text())
+    _no_relayout(compiled.as_text())
 
 
 def test_gpt2_medium_dsm_step_with_flash_attention_fits_one_v5e(one_chip,
                                                                  monkeypatch):
     """All 24 layers of GPT-2 medium, W=2, with the fused attention kernel
-    (eight groups of two heads of 64).  Arguments plus temp (16.6 GiB) add
-    up buffers that are never live at once; the bound here is the
-    compiler's own peak of live bytes.  With n_embd = S = 1024 every
+    (eight groups of two heads of 64).  The bound is the compiler's own
+    peak of live bytes: 11.47 GB, where worker-first stacked state took
+    15.90 GB and made XLA recompute the logits, the MLP's w1 product and
+    six attention projections to fit.  With n_embd = S = 1024 every
     projection is (W, B, S, 1024) too, so a score product is told by its
     16-head axis."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
@@ -144,9 +193,10 @@ def test_gpt2_medium_dsm_step_with_flash_attention_fits_one_v5e(one_chip,
                                         n_layers=24)
     mem = compiled.memory_analysis()
     _aliases_the_state(mem, state)
-    assert mem.peak_memory_in_bytes < V5E_HBM
+    assert mem.peak_memory_in_bytes < 12.5e9
     _kernel_path_in_attention(compiled.as_text(),
                               score_dims=r"[\d,]*16,1024,1024")
+    _no_relayout(compiled.as_text())
 
 
 @pytest.mark.parametrize("shape", [
