@@ -11,22 +11,22 @@ Paper claims being checked:
   T4: Lookahead (n=1) improves on plain AdamW.
   T5: signed Lookahead (n=1) improves on plain AdamW.
   T6: signed SlowMo sits between SlowMo and Alg.1; global AdamW ~ SlowMo.
+
+Prints ``name,value,derived`` CSV rows.  ``--quick`` shrinks sweeps/steps;
+``--only`` takes a comma list of targets (default: all of them).
+
+Run:  PYTHONPATH=src python examples/paper_tables.py --quick --only table2
 """
 
 from __future__ import annotations
 
+import argparse
 
 import numpy as np
 
-from repro.configs.base import ModelConfig
+from repro.configs import NANO
 from repro.data.pipeline import MarkovCorpus
 from repro.train.trainer import TrainSettings, run_training
-
-NANO = ModelConfig(
-    name="nano_gpt", family="lm", n_layers=2, d_model=64, n_heads=4,
-    n_kv_heads=4, d_ff=128, vocab_size=64, head_dim=16, mlp_gated=False,
-    act="gelu", dtype="float32", param_dtype="float32", vocab_pad_to=64,
-)
 
 _CORPUS = None
 
@@ -44,7 +44,7 @@ def _settings(**kw) -> TrainSettings:
         warmup=5, eval_every=30, heterogeneous=True,
         # CPU-scale horizon is ~1000x shorter than the paper's 100k steps;
         # momentum time-constants are scaled accordingly (beta 0.9/0.95
-        # instead of Lion's 0.95/0.98). See EXPERIMENTS.md SScale-notes.
+        # instead of Lion's 0.95/0.98).
         dsm_beta1=0.9, dsm_beta2=0.95,
     )
     base.update(kw)
@@ -182,3 +182,43 @@ def table_noise(steps=100, tau=8, quick=False):
         ("slowmo_noisy", f"{tau}x", sm["final_eval"], sm["comm_rounds"], sm["sweep_params"]),
         ("improv_noisy_pct", f"{tau}x", improv, 0, {}),
     ]
+
+
+TABLES = (("table2", table2), ("table3", table3), ("table45", table45),
+          ("table6", table6), ("table_noise", table_noise))
+TARGETS = tuple(name for name, _ in TABLES) + ("curves",)
+
+
+def _emit(name, value, derived=""):
+    print(f"{name},{value},{derived}", flush=True)
+
+
+def main(argv=None) -> None:
+    ap = argparse.ArgumentParser()
+    ap.add_argument("--quick", action="store_true")
+    ap.add_argument("--only", default="all",
+                    help="comma list of " + ",".join(TARGETS))
+    args = ap.parse_args(argv)
+    only = set(args.only.split(","))
+    unknown = only - set(TARGETS) - {"all"}
+    if unknown:
+        ap.error(f"unknown targets {sorted(unknown)}; pick from {TARGETS}")
+
+    def want(x):
+        return "all" in only or x in only
+
+    for tname, fn in TABLES:
+        if not want(tname):
+            continue
+        for name, red, val, commr, params in fn(quick=args.quick):
+            _emit(f"{tname}_{name}", f"{val:.4f}",
+                  f"comm_red={red};rounds={commr};{params}")
+
+    if want("curves"):
+        for algo, pts in curves(quick=args.quick).items():
+            _emit(f"curve_{algo}_final", f"{pts[-1][3]:.4f}",
+                  f"comm_rounds={pts[-1][1]}")
+
+
+if __name__ == "__main__":
+    main()

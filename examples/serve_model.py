@@ -1,12 +1,10 @@
 """Serving example: train a byte-level model on this repo's own source code
-with DSM, then serve batched greedy completions through the production
-decode path (prefill + KV-cache decode_step — the same functions the
-decode_32k / long_500k dry-runs lower).
+with DSM, then serve batched greedy completions through the model's
+decode path (prefill + KV-cache decode_step).
 
 Run:  PYTHONPATH=src python examples/serve_model.py
 """
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 

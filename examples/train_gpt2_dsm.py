@@ -2,10 +2,10 @@
 Algorithm 1, mirroring the paper's §4 protocol (AdamW base optimizer,
 cosine LR with warmup, Lion betas for the global step, tau=12).
 
-Defaults are CPU-sized (reduced width, 120 outer steps). On a real cluster,
-raise --layers/--d-model/--seq to the paper's 124M config (12L/768) — the
-training code is identical; the dry-run (launch/dryrun.py) proves the
-full-size sharded lowering.
+Defaults are CPU-sized (reduced width, 120 outer steps). On a TPU, raise
+--layers/--d-model/--seq to the paper's 124M config (12L/768) — the
+training code is identical; ``tests/test_tpu_compile.py`` compiles the
+full-size outer step for a described v5e.
 
 Run:  PYTHONPATH=src python examples/train_gpt2_dsm.py --steps 120
 """

@@ -4,7 +4,7 @@ Three layers (see docs/analysis.md):
 
   * ``hlo_audit``  — lower any jitted step to compiled HLO, count the
     collective ops with their shapes, and check them against the per-phase
-    budgets derived from the analytic model in ``benchmarks/comm.py``.
+    budgets derived from the analytic model in ``repro.analysis.comm``.
     The paper's claim IS a collective budget (one reduction per tau local
     steps, none inside them); this makes it machine-checked.
   * ``lint``       — RPR0xx AST rules for the bug classes nothing else

@@ -2,7 +2,7 @@
 
   audit  — compile the dense / device-parallel / ZeRO-sharded outer steps
            and the bare local phase, parse their collectives, and check
-           them against the budgets derived from benchmarks/comm.py; also
+           them against the budgets derived from repro.analysis.comm; also
            print each program's relayout report (XLA remat clones and
            bytes of re-laid-out state, hlo_audit.relayout_report).
            Forces a multi-device host (``--devices``, default 8) BEFORE

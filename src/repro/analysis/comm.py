@@ -1,7 +1,8 @@
 """Communication-volume accounting (the paper's 'Com. red.' column).
 
-Analytic bytes-per-outer-step for every algorithm, cross-checked against
-the dry-run's HLO collective parse for DSM (benchmarks/run.py prints both).
+Analytic bytes-per-outer-step for every algorithm; the runtime comm
+ledger (``repro.obs.ledger``) joins it with the compiled step's collectives
+as parsed by ``repro.analysis.hlo_audit.parse_collectives``.
 
 ``phase_collective_budget`` turns the same model into the machine-checked
 per-phase budgets consumed by the static auditor

@@ -3,13 +3,13 @@
 The paper's headline claim is a *collective budget*: sign momentum
 communicates once per tau local steps (one worker reduction + , when
 ZeRO-sharded, one gather), and the tau local steps themselves are
-communication-free.  ``benchmarks/comm.py`` models that analytically;
+communication-free.  ``repro.analysis.comm`` models that analytically;
 this module checks that the COMPILED program agrees, by lowering any
 jitted step to its post-partitioning HLO text, parsing every collective
 op with its shape, and comparing op counts and payload bytes against the
 declared per-phase budget.
 
-Budget semantics (``benchmarks.comm.phase_collective_budget``):
+Budget semantics (``repro.analysis.comm.phase_collective_budget``):
 
   * a LOGICAL reduction round may lower as ``reduce-scatter`` on
     collective-capable backends or as ``all-reduce`` (+ local slice) under
@@ -181,7 +181,7 @@ class CollectiveBudget:
         ``n_param_leaves`` and the payload bytes come from it (reductions
         run in the f32 momentum dtype, so the payload floor is 4 B/elem).
         """
-        from benchmarks.comm import phase_collective_budget
+        from repro.analysis.comm import phase_collective_budget
 
         import jax
 
@@ -303,7 +303,7 @@ def standard_audit(n_workers: int = 4, tau: int = 2,
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from benchmarks.tables import NANO
+    from repro.configs import NANO
     from repro.core import (DSMConfig, constant, dsm_init, get_base_optimizer,
                             make_dsm_step, make_local_phase)
     from repro.core.workers import worker_pspecs

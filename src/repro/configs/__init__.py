@@ -3,6 +3,7 @@
 from repro.configs.base import (
     ARCH_IDS,
     INPUT_SHAPES,
+    NANO,
     PAPER_ARCH_IDS,
     InputShape,
     ModelConfig,

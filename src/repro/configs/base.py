@@ -116,9 +116,18 @@ class ModelConfig:
         return self.n_layers % len(self.pattern)
 
 
+# The CPU-sized GPT the tests, the CI smoke run (``--arch nano``) and the
+# paper-table analogs train.
+NANO = ModelConfig(
+    name="nano_gpt", family="lm", n_layers=2, d_model=64, n_heads=4,
+    n_kv_heads=4, d_ff=128, vocab_size=64, head_dim=16, mlp_gated=False,
+    act="gelu", dtype="float32", param_dtype="float32", vocab_pad_to=64,
+)
+
+
 @dataclasses.dataclass(frozen=True)
 class TopologyConfig:
-    """How this arch maps onto the production mesh for training."""
+    """Training defaults for this arch."""
 
     n_workers_single: int = 16   # paper's n, single-pod (worker axis size)
     n_workers_multi: int = 32    # multi-pod
@@ -129,9 +138,6 @@ class TopologyConfig:
     remat: bool = True
     remat_policy: str = "full"   # "full" | "dots" (save matmul outputs —
                                  # fewer recompute bytes, higher peak)
-    attn_tp: bool = True         # False: replicate attention weights over the
-                                 # model axis (kills hd-split score reshards
-                                 # for small-kv archs; SPerf hillclimb)
     # which decode shapes this arch supports (DESIGN.md skips)
     supports_long_context: bool = False
 

@@ -1,8 +1,9 @@
 """input_specs(): ShapeDtypeStruct stand-ins for every model input.
 
-Weak-type-correct, shardable, no device allocation — the dry-run lowers
-against these.  Audio/VLM frontends are STUBS per assignment: the specs
-provide precomputed frame/patch embeddings at d_model.
+Weak-type-correct, no device allocation: ``jax.eval_shape`` and
+``jax.jit(...).lower`` take these in place of arrays.  Audio/VLM frontends
+are STUBS per assignment: the specs provide precomputed frame/patch
+embeddings at d_model.
 """
 
 from __future__ import annotations
@@ -72,7 +73,7 @@ def decode_specs(cfg: ModelConfig, shape: InputShape) -> dict:
 
 
 def abstract_params(cfg: ModelConfig):
-    """Parameter ShapeDtypeStructs without allocating (for dry-run)."""
+    """Parameter ShapeDtypeStructs without allocating."""
     return jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0), cfg))
 
 
@@ -81,14 +82,3 @@ def param_count(cfg: ModelConfig) -> int:
 
     return sum(math.prod(l.shape) for l in jax.tree.leaves(abstract_params(cfg)))
 
-
-def active_param_count(cfg: ModelConfig) -> int:
-    """Active params per token (MoE: top_k + shared experts only)."""
-    total = param_count(cfg)
-    if cfg.n_experts == 0:
-        return total
-    # subtract inactive expert weights
-    n_moe_layers = sum(1 for k in cfg.layer_kinds() if k.endswith(":moe"))
-    per_expert = (2 + int(cfg.mlp_gated)) * cfg.d_model * cfg.d_ff
-    inactive = n_moe_layers * (cfg.n_experts - cfg.top_k) * per_expert
-    return total - inactive

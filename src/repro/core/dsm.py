@@ -14,11 +14,10 @@ Structure (one *outer* step t):
 
 Workers are represented by an axis ``W`` on params / optimizer state /
 batches: leading on batches and on most leaves, second on the layer-stacked
-leaves the model scans (``repro.core.workers``).  Under the production mesh
-this axis is sharded over the
-``("pod","data")`` axes, so step 1 emits **no inter-worker collectives**
+leaves the model scans (``repro.core.workers``).  On a mesh this axis is
+sharded over ``"worker"``, so step 1 emits **no inter-worker collectives**
 (everything is elementwise in W) and step 2 lowers to a single all-reduce
-over (pod, data) — the tau-amortized communication the paper is about.
+over ``"worker"`` — the tau-amortized communication the paper is about.
 
 Instances (paper §2 "Algorithm instances"):
   * tau=1, beta1=beta2=beta, lam=0    -> signSGD with momentum (eq. 3)
@@ -343,7 +342,7 @@ def make_local_phase(
     if mesh is None or "worker" not in mesh.axis_names:
         raise ValueError(
             "device_parallel local phase needs a mesh with a 'worker' axis "
-            "(repro.launch.mesh.training_mesh / host_training_mesh)"
+            "(repro.launch.mesh.host_training_mesh)"
         )
 
     from jax.sharding import PartitionSpec as P

@@ -1,12 +1,10 @@
-"""Sharding rules: map parameter / batch / cache pytrees onto the mesh.
+"""Sharding rules: map parameter pytrees onto the training mesh.
 
 Training mesh axes: ``("worker", "zero", "model")``
-  worker — the paper's n workers (local-step isolation; pod*data rows)
+  worker — the paper's n workers (local-step isolation)
   zero   — FSDP/ZeRO shard *within* a worker (paper §2: "ZeRO-2 for local
            steps ... faster intra-node communication")
   model  — tensor parallel within a worker
-
-Serving mesh axes: ``("data", "model")``.
 
 Rules are name-aware (Megatron-style column/row parallel) with a generic
 divisibility fallback; dims that don't divide are replicated.
@@ -14,13 +12,10 @@ divisibility fallback; dims that don't divide are replicated.
 
 from __future__ import annotations
 
-import re
 from typing import Any, Optional
 
 import jax
-import jax.numpy as jnp
-import numpy as np
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import PartitionSpec as P
 
 from repro.layout import is_stacked
 
@@ -119,80 +114,3 @@ def param_pspecs(
 
     return jax.tree_util.tree_map_with_path(spec_for, abstract_params)
 
-
-def train_batch_pspecs(batch: PyTree, zero: int = 1, model: int = 1) -> PyTree:
-    """Batch leaves (W, tau, accum, B_micro, ...): worker on W, zero on B.
-
-    Float leaves (stub frame/patch embeddings) also shard their trailing
-    feature dim over model — they are the dominant input bytes for
-    audio/VLM archs.
-    """
-
-    def spec_for(leaf):
-        spec = [None] * len(leaf.shape)
-        spec[0] = "worker"
-        if (len(leaf.shape) > 3 and zero > 1
-                and leaf.shape[3] % zero == 0 and leaf.shape[3] >= zero):
-            spec[3] = "zero"
-        if (model > 1 and len(leaf.shape) > 4
-                and jnp.issubdtype(leaf.dtype, jnp.floating)
-                and leaf.shape[-1] % model == 0 and leaf.shape[-1] >= model):
-            spec[-1] = "model"
-        return P(*spec)
-
-    return jax.tree.map(spec_for, batch)
-
-
-def serve_batch_pspecs(batch: PyTree, data: int, model: int) -> PyTree:
-    """Prefill batch (B, S, ...): B over data (fallback: S)."""
-
-    def spec_for(leaf):
-        shape = leaf.shape
-        spec = [None] * len(shape)
-        if len(shape) >= 1 and shape[0] % data == 0 and shape[0] >= data:
-            spec[0] = "data"
-        elif len(shape) >= 2 and shape[1] % data == 0:
-            spec[1] = "data"
-        return P(*spec)
-
-    return jax.tree.map(spec_for, batch)
-
-
-def cache_pspecs(cache: PyTree, data: int, model: int, stacked_hint: bool = True) -> PyTree:
-    """KV/state cache sharding: batch dim over data (fallback: seq), last
-    divisible dim over model."""
-
-    def spec_for(path, leaf):
-        shape = leaf.shape
-        spec = [None] * len(shape)
-        i0 = 1 if (is_stacked(path) and len(shape) > 1) else 0
-        taken = set()
-        # data axis: prefer batch dim (i0), else next dims
-        dd = None
-        for i in range(i0, len(shape)):
-            if shape[i] % data == 0 and shape[i] >= data:
-                dd = i
-                break
-        if dd is not None and data > 1:
-            spec[dd] = "data"
-            taken.add(dd)
-        # model axis: last divisible dim
-        if model > 1:
-            for i in range(len(shape) - 1, i0 - 1, -1):
-                if i not in taken and shape[i] % model == 0 and shape[i] >= model:
-                    spec[i] = "model"
-                    break
-        return P(*spec)
-
-    return jax.tree_util.tree_map_with_path(spec_for, cache)
-
-
-def to_named(pspecs: PyTree, mesh: Mesh) -> PyTree:
-    return jax.tree.map(
-        lambda s: NamedSharding(mesh, s), pspecs,
-        is_leaf=lambda x: isinstance(x, P),
-    )
-
-
-def replicated(tree: PyTree, mesh: Mesh) -> PyTree:
-    return jax.tree.map(lambda _: NamedSharding(mesh, P()), tree)

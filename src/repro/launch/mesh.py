@@ -1,44 +1,10 @@
-"""Production meshes and derived (worker, zero, model) training meshes.
-
-``make_production_mesh`` is a FUNCTION (not module-level) so importing this
-module never touches jax device state.
-"""
+"""The (worker, zero, model) training mesh over the local devices."""
 
 from __future__ import annotations
 
 import jax
 import numpy as np
 from jax.sharding import Mesh
-
-MODEL_PAR = 16  # chips along the model axis (both meshes)
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def training_mesh(base_mesh: Mesh, n_workers: int) -> Mesh:
-    """Reshape the production mesh into (worker, zero, model).
-
-    The paper's worker i = one model-parallel group; ``zero`` is the FSDP
-    shard inside a worker (paper §2's intra-node ZeRO).  pod x data rows are
-    split into ``n_workers`` groups of ``zero`` rows each.
-    """
-    devices = np.asarray(base_mesh.devices)
-    model = devices.shape[-1]
-    rows = devices.reshape(-1, model)          # (pod*data, model)
-    n_rows = rows.shape[0]
-    if n_rows % n_workers != 0:
-        raise ValueError(
-            f"n_workers={n_workers} does not divide the {n_rows} model-parallel "
-            f"groups of the production mesh {tuple(devices.shape)}; pick a "
-            f"worker count from the divisors of {n_rows}"
-        )
-    zero = n_rows // n_workers
-    grid = rows.reshape(n_workers, zero, model)
-    return Mesh(grid, ("worker", "zero", "model"))
 
 
 def host_training_mesh(n_workers: int, model: int = 1) -> Mesh:
@@ -74,14 +40,3 @@ def host_training_mesh(n_workers: int, model: int = 1) -> Mesh:
     grid = devices[: worker * zero * model].reshape(worker, zero, model)
     return Mesh(grid, ("worker", "zero", "model"))
 
-
-def serving_mesh(base_mesh: Mesh) -> Mesh:
-    """Reshape into (data, model) with pod folded into data."""
-    devices = np.asarray(base_mesh.devices)
-    model = devices.shape[-1]
-    rows = devices.reshape(-1, model)
-    return Mesh(rows, ("data", "model"))
-
-
-def mesh_dims(mesh: Mesh) -> dict:
-    return dict(zip(mesh.axis_names, mesh.devices.shape))

@@ -1,29 +1,25 @@
 """Training launcher.
 
-Two modes:
-  * local (default)   — trains on the devices JAX finds (TPU chips, or the
-    CPU under ``JAX_PLATFORMS=cpu``):
-      PYTHONPATH=src python -m repro.launch.train --arch gpt2_small \\
-          --algorithm dsm --tau 12 --seq 1024 --steps 100
-    ``--arch`` accepts ``<id>`` (FULL config at published widths; on one
-    TPU v5e chip GPT-2 small fits at W=4 and GPT-2 medium at W=2, each at
-    b_micro=4, seq=1024),
-    ``<id>_smoke`` (reduced family variant), or ``nano``.  Rematerialization
-    follows the arch's ``TopologyConfig.remat``.
-  * plan              — prints the production launch plan for the 16x16 /
-    2x16x16 mesh (worker count, shardings, per-chip memory from the
-    dry-run artifact) without touching devices:
-      python -m repro.launch.train --arch deepseek_67b --plan
+Trains on the devices JAX finds (TPU chips, or the CPU under
+``JAX_PLATFORMS=cpu``):
+
+    PYTHONPATH=src python -m repro.launch.train --arch gpt2_small \\
+        --algorithm dsm --tau 12 --seq 1024 --steps 100
+
+``--arch`` accepts ``<id>`` (FULL config at published widths; on one TPU
+v5e chip GPT-2 small fits at W=4 and GPT-2 medium at W=2, each at
+b_micro=4, seq=1024), ``<id>_smoke`` (reduced family variant), or ``nano``
+(``repro.configs.NANO``).  Rematerialization follows the arch's
+``TopologyConfig.remat``.
 """
 
 from __future__ import annotations
 
 import argparse
-import json
 import os
 from typing import Callable, Optional
 
-from repro.configs import load_arch
+from repro.configs import NANO, load_arch
 from repro.configs.base import ModelConfig
 
 # fixed, so the cache key's path is the same on every run of this checkout
@@ -52,11 +48,7 @@ def enable_compile_cache() -> Optional[str]:
 
 def _resolve_arch(name: str) -> tuple[ModelConfig, object]:
     if name == "nano":
-        from benchmarks.tables import NANO
-
-        cfg = NANO
-        topo = load_arch("gpt2_small").TOPO
-        return cfg, topo
+        return NANO, load_arch("gpt2_small").TOPO
     if name.endswith("_smoke"):
         mod = load_arch(name[: -len("_smoke")])
         return mod.SMOKE, mod.TOPO
@@ -127,7 +119,6 @@ def build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--sanitize-nans", action="store_true",
                     help="run the loop under jax_debug_nans (chaos tier: "
                          "masked NaNs must never reach a jit output)")
-    ap.add_argument("--plan", action="store_true")
     return ap
 
 
@@ -167,34 +158,6 @@ def train(args: argparse.Namespace, log: Optional[Callable] = print):
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
-
-    if args.plan:
-        cfg, topo = _resolve_arch(args.arch)
-        tau = args.tau or topo.tau
-        from repro.configs import specs as S
-
-        n = S.param_count(cfg)
-        plan = {
-            "arch": args.arch,
-            "params_B": round(n / 1e9, 3),
-            "mesh_single_pod": {"shape": [16, 16], "axes": ["data", "model"],
-                                "n_workers": topo.n_workers_single},
-            "mesh_multi_pod": {"shape": [2, 16, 16], "axes": ["pod", "data", "model"],
-                               "n_workers": topo.n_workers_multi},
-            "tau": tau,
-            "base_opt": topo.base_opt,
-            "grad_accum": topo.grad_accum,
-            "dryrun_cmd": (
-                f"PYTHONPATH=src python -m repro.launch.dryrun --arch {args.arch} "
-                "--shape train_4k --mesh both"),
-        }
-        dr = f"experiments/dryrun/{args.arch}.train_4k.singlepod.json"
-        if os.path.exists(dr):
-            rec = json.load(open(dr))
-            plan["per_chip_peak_GB"] = round(rec["memory"]["peak_bytes"] / 1e9, 2)
-            plan["dominant_roofline_term"] = rec.get("dominant")
-        print(json.dumps(plan, indent=2))
-        return
 
     enable_compile_cache()
     _, _, _, result = train(args)

@@ -6,7 +6,7 @@ Layer stacking: full repeats of the pattern are *scanned* (params stacked on
 a leading block axis — keeps HLO size O(pattern) instead of O(n_layers));
 the remainder layers are unrolled.
 
-Public API (used by trainer / dryrun / serve):
+Public API (used by the trainer and serve):
   init_params(key, cfg)                       -> params
   loss_fn(params, batch, cfg)                 -> scalar loss
   prefill(params, batch, cfg)                 -> (last_logits, cache)
@@ -190,13 +190,8 @@ def _apply_block(p, kind, x, positions, cfg, enc_out=None, collect_cache=False):
 
 
 def _run_stack(stack, pattern, x, positions, cfg, enc_out=None, remat=True,
-               unroll=False, remat_policy="full"):
-    """Scanned pattern repeats + unrolled remainder. Returns (x, aux_sum).
-
-    ``unroll=True`` replaces the layer scan with a python loop — used by the
-    roofline pass, because XLA's cost_analysis counts while-loop bodies once
-    regardless of trip count.  Numerically identical.
-    """
+               remat_policy="full"):
+    """Scanned pattern repeats + unrolled remainder. Returns (x, aux_sum)."""
 
     def body(carry, block_params):
         x, aux = carry
@@ -216,15 +211,7 @@ def _run_stack(stack, pattern, x, positions, cfg, enc_out=None, remat=True,
         body_fn = body
     aux0 = jnp.zeros((), F32)
     if stack[STACK_KEY]:
-        if unroll:
-            nb = jax.tree.leaves(stack[STACK_KEY])[0].shape[0]
-            carry = (x, aux0)
-            for i in range(nb):
-                bp = jax.tree.map(lambda a: a[i], stack[STACK_KEY])
-                carry, _ = body_fn(carry, bp)
-            x, aux = carry
-        else:
-            (x, aux), _ = jax.lax.scan(body_fn, (x, aux0), stack[STACK_KEY])
+        (x, aux), _ = jax.lax.scan(body_fn, (x, aux0), stack[STACK_KEY])
     else:
         aux = aux0
     for i, p in enumerate(stack["rem"]):
@@ -238,17 +225,16 @@ def _embed(params, tokens, cfg):
     return e * math.sqrt(cfg.d_model)
 
 
-def _encode(params, frames, cfg, remat=True, unroll=False):
+def _encode(params, frames, cfg, remat=True):
     """Whisper-style encoder over (stub) frame embeddings (B, enc_len, d)."""
     x = frames.astype(cfg.act_dtype)
     positions = jnp.arange(x.shape[1])
     x, _ = _run_stack(params["encoder"], ("encattn:dense",), x, positions, cfg,
-                      remat=remat, unroll=unroll)
+                      remat=remat)
     return L.rmsnorm(params["enc_norm"], x, cfg.norm_eps)
 
 
-def hidden_states(params, batch, cfg, remat=True, unroll=False,
-                  remat_policy="full"):
+def hidden_states(params, batch, cfg, remat=True, remat_policy="full"):
     """Full forward to final hidden states. Returns (h, aux, n_prefix).
 
     ``n_prefix`` = number of non-text positions (VLM patches) to exclude
@@ -257,7 +243,7 @@ def hidden_states(params, batch, cfg, remat=True, unroll=False,
     enc_out = None
     n_prefix = 0
     if cfg.family == "encdec":
-        enc_out = _encode(params, batch["frames"], cfg, remat=remat, unroll=unroll)
+        enc_out = _encode(params, batch["frames"], cfg, remat=remat)
         x = _embed(params, batch["tokens"], cfg)
     elif cfg.family == "vlm":
         patches = (batch["patches"].astype(cfg.act_dtype)
@@ -270,7 +256,7 @@ def hidden_states(params, batch, cfg, remat=True, unroll=False,
 
     positions = jnp.arange(x.shape[1])
     x, aux = _run_stack(params["decoder"], cfg.pattern, x, positions, cfg,
-                        enc_out, remat, unroll, remat_policy)
+                        enc_out, remat, remat_policy)
     return L.rmsnorm(params["final_norm"], x, cfg.norm_eps), aux, n_prefix
 
 
@@ -280,11 +266,11 @@ def _logits(params, h, cfg):
     return h.astype(F32) @ params["lm_head"].astype(F32)
 
 
-def loss_fn(params, batch, cfg, remat: bool = True, unroll: bool = False,
+def loss_fn(params, batch, cfg, remat: bool = True,
             remat_policy: str = "full") -> jnp.ndarray:
     """Next-token CE, chunked over the sequence to bound logits memory."""
     h, aux, n_prefix = hidden_states(params, batch, cfg, remat=remat,
-                                     unroll=unroll, remat_policy=remat_policy)
+                                     remat_policy=remat_policy)
     tokens = batch["tokens"]
     B, S_text = tokens.shape
     h_text = h[:, n_prefix:]
@@ -418,7 +404,7 @@ def _decode_block(p, kind, cache, x, pos, cfg, max_len):
     return x, new_cache
 
 
-def decode_step(params, cache, tokens, pos, cfg, unroll: bool = False):
+def decode_step(params, cache, tokens, pos, cfg):
     """tokens: (B,) int32; pos: scalar int32. Returns (logits (B,V), cache)."""
     x = _embed(params, tokens[:, None], cfg)
     max_len = None
@@ -437,20 +423,9 @@ def decode_step(params, cache, tokens, pos, cfg, unroll: bool = False):
 
     new_cache = {STACK_KEY: {}, "rem": []}
     if params["decoder"][STACK_KEY]:
-        if unroll:
-            nb = jax.tree.leaves(params["decoder"][STACK_KEY])[0].shape[0]
-            ys = []
-            for i in range(nb):
-                bp = jax.tree.map(lambda a: a[i], params["decoder"][STACK_KEY])
-                bc = jax.tree.map(lambda a: a[i], cache[STACK_KEY])
-                x, nc = body(x, (bp, bc))
-                ys.append(nc)
-            new_cache[STACK_KEY] = jax.tree.map(
-                lambda *xs: jnp.stack(xs), *ys) if ys else {}
-        else:
-            x, new_cache[STACK_KEY] = jax.lax.scan(
-                body, x, (params["decoder"][STACK_KEY], cache[STACK_KEY])
-            )
+        x, new_cache[STACK_KEY] = jax.lax.scan(
+            body, x, (params["decoder"][STACK_KEY], cache[STACK_KEY])
+        )
     for i, p in enumerate(params["decoder"]["rem"]):
         x, nc = _decode_block(p, cfg.pattern[i], cache["rem"][i], x, pos, cfg, max_len)
         new_cache["rem"].append(nc)
@@ -522,7 +497,7 @@ def _rglru_final_state(p, h, cfg):
     return {"h": hs[:, -1], "conv": conv_tail}
 
 
-def prefill(params, batch, cfg, remat: bool = True, unroll: bool = False):
+def prefill(params, batch, cfg, remat: bool = True):
     """Forward over prompt tokens; returns (last-token logits, cache)."""
     enc_out = None
     if cfg.family == "encdec":
@@ -552,16 +527,7 @@ def prefill(params, batch, cfg, remat: bool = True, unroll: bool = False):
     body_fn = jax.checkpoint(body) if remat else body
     cache = {STACK_KEY: {}, "rem": []}
     if params["decoder"][STACK_KEY]:
-        if unroll:
-            nb = jax.tree.leaves(params["decoder"][STACK_KEY])[0].shape[0]
-            ys = []
-            for i in range(nb):
-                bp = jax.tree.map(lambda a: a[i], params["decoder"][STACK_KEY])
-                x, c = body_fn(x, bp)
-                ys.append(c)
-            cache[STACK_KEY] = jax.tree.map(lambda *xs: jnp.stack(xs), *ys) if ys else {}
-        else:
-            x, cache[STACK_KEY] = jax.lax.scan(body_fn, x, params["decoder"][STACK_KEY])
+        x, cache[STACK_KEY] = jax.lax.scan(body_fn, x, params["decoder"][STACK_KEY])
     for i, p in enumerate(params["decoder"]["rem"]):
         x, entry = _prefill_block_cache(p, cfg.pattern[i], x, positions, cfg, enc_out)
         cache["rem"].append(entry)

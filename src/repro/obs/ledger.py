@@ -1,8 +1,8 @@
 """Comm ledger: observed (compiled-HLO) vs predicted (analytic) collective
 bytes for the training step (docs/observability.md).
 
-The analytic model (``benchmarks.comm``) predicts what Algorithm 1 *should*
-communicate per outer step; ``repro.analysis.hlo_audit`` already parses
+The analytic model (``repro.analysis.comm``) predicts what Algorithm 1
+*should* communicate per outer step; ``repro.analysis.hlo_audit`` parses
 what the compiled program *actually* contains.  The ledger joins the two
 at trainer startup: it lowers the live jitted step — same function, same
 argument shardings, same mesh — parses its collectives, and emits an
@@ -42,12 +42,12 @@ def compile_time_ledger(
 
     ``params``: the global buffer pytree the phase moves (x0) — payload
     bytes use the reduce dtype floor of 4 B/elem, matching the auditor.
-    ``phase``: one of ``benchmarks.comm.PHASES``.
+    ``phase``: one of ``repro.analysis.comm.PHASES``.
     """
     import jax
 
-    from benchmarks.comm import (GATHER_CLASS, PHASES, REDUCE_CLASS,
-                                 wire_bytes_for_payload)
+    from repro.analysis.comm import (GATHER_CLASS, PHASES, REDUCE_CLASS,
+                                     wire_bytes_for_payload)
     from repro.analysis.hlo_audit import parse_collectives
 
     if phase not in PHASES:

@@ -22,9 +22,9 @@ The collective cost is bounded by construction:
     (``repro.distributed.zero.sharded_stat_sums``).
 
 Both fit inside the ``n_metric_reductions = 2`` scalar-reduction allowance
-the audited per-phase budgets already carry (benchmarks/comm.py), which is
-how ``python -m repro.analysis audit`` proves the instrumented step keeps
-the paper's collective budget unchanged.
+the audited per-phase budgets already carry (``repro.analysis.comm``),
+which is how ``python -m repro.analysis audit`` proves the instrumented
+step keeps the paper's collective budget unchanged.
 
 This module is jit-reachable: no host reads, no traced-value branches.
 Host-side decoding (pack -> dict) lives in ``repro.obs.sinks``.

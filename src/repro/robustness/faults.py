@@ -128,7 +128,8 @@ class FaultPlan:
 
     def dropped_frac(self) -> float:
         """Fraction of (round, worker) contributions dropped — for comm
-        accounting (benchmarks.comm ``survivor_frac = 1 - dropped_frac``)."""
+        accounting (``repro.analysis.comm``: ``survivor_frac = 1 -
+        dropped_frac``)."""
         return float(self.drop.mean()) if self.drop.size else 0.0
 
 

@@ -1,8 +1,7 @@
 """Batched serving: prefill a prompt batch, then autoregressive decode.
 
-CPU-scale engine used by examples/serve_model.py and the integration tests;
-the production decode path is the same ``decode_step`` the dry-run lowers
-for decode_32k / long_500k.
+CPU-scale engine used by examples/serve_model.py and the integration tests,
+over the model's own ``prefill`` and ``decode_step``.
 """
 
 from __future__ import annotations

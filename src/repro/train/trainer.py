@@ -1,8 +1,8 @@
 """Training harness: runs any algorithm (DSM or baseline) on any ModelConfig.
 
 This is the engine behind ``python -m repro.launch.train``, the
-paper-reproduction experiments (benchmarks/), the runnable examples and
-``chip_smoke.py``.  It runs on whatever devices JAX finds: TPU chips, or the
+paper-table analogs (``examples/paper_tables.py``), the other runnable
+examples and ``chip_smoke.py``.  It runs on whatever devices JAX finds: TPU chips, or the
 CPU for tests.  Workers are a leading W axis, vmapped on one device or
 shard_mapped one per device (``device_parallel_local``).
 """

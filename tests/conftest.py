@@ -1,5 +1,5 @@
-"""Shared fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches must
-see 1 CPU device; only launch/dryrun.py forces 512 placeholder devices."""
+"""Shared fixtures. NOTE: no XLA_FLAGS here — tier-1 tests see 1 CPU device;
+the multi-device tests force their device count in a subprocess."""
 
 import jax
 import pytest

@@ -117,7 +117,7 @@ def test_audit_passes_within_budget():
 
 
 def test_phase_budget_shapes():
-    from benchmarks.comm import phase_collective_budget
+    from repro.analysis.comm import phase_collective_budget
 
     local = phase_collective_budget("local", n_param_leaves=10,
                                     payload_bytes=1000)

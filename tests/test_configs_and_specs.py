@@ -1,5 +1,5 @@
 """Config/spec invariants across ALL archs x input shapes (catches config
-drift before it reaches the dry-run)."""
+drift before it reaches a compile)."""
 
 import jax
 import jax.numpy as jnp
@@ -13,9 +13,9 @@ from repro.configs import (
     arch_supports_shape,
     load_arch,
 )
+from repro.analysis.comm import bytes_per_outer_step
 from repro.configs import specs as S
 from repro.core.schedules import cosine_with_warmup
-from benchmarks.comm import bytes_per_outer_step
 
 
 @pytest.mark.parametrize("arch_id", ARCH_IDS)

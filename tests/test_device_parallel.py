@@ -127,7 +127,7 @@ def test_trainer_device_parallel_wiring():
 # ---------------------------------------------------------------------------
 
 def test_comm_model_reports_local_compute_deduplication():
-    from benchmarks.comm import bytes_per_outer_step
+    from repro.analysis.comm import bytes_per_outer_step
 
     rep = bytes_per_outer_step("gpt2_small", "dsm", tau=12, n_workers=8)
     dp = bytes_per_outer_step("gpt2_small", "dsm", tau=12, n_workers=8,

@@ -326,7 +326,7 @@ def test_profile_steps_capture_holds_the_step_annotations(tmp_path):
 # ---------------------------------------------------------------------------
 
 def test_wire_bytes_model_matches_outer_step_report():
-    from benchmarks.comm import bytes_per_outer_step, wire_bytes_for_payload
+    from repro.analysis.comm import bytes_per_outer_step, wire_bytes_for_payload
 
     payload = 1 << 20
     assert wire_bytes_for_payload(payload, "dsm", tau=12) == (2 * payload, 1)

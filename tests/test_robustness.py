@@ -330,7 +330,7 @@ def test_resume_with_faults_replays_the_plan():
 # ---------------------------------------------------------------------------
 
 def test_comm_accounting_under_dropout():
-    from benchmarks.comm import bytes_per_outer_step
+    from repro.analysis.comm import bytes_per_outer_step
 
     full = bytes_per_outer_step("gpt2_small", "dsm", tau=12)
     faulty = bytes_per_outer_step("gpt2_small", "dsm", tau=12,

@@ -174,7 +174,7 @@ def test_sharded_training_matches_replicated_8dev():
 # ---------------------------------------------------------------------------
 
 def test_comm_model_reports_sharded_reduction():
-    from benchmarks.comm import bytes_per_outer_step
+    from repro.analysis.comm import bytes_per_outer_step
 
     rep = bytes_per_outer_step("gpt2_small", "dsm", tau=12)
     sh = bytes_per_outer_step("gpt2_small", "dsm", tau=12,

@@ -2,7 +2,7 @@
 variant of each assigned arch family, run one forward + one DSM outer
 train step + one decode step on CPU; assert output shapes and finiteness.
 
-FULL configs are exercised only via the dry-run (no allocation here).
+FULL configs are not allocated here (``jax.eval_shape`` only).
 """
 
 import jax

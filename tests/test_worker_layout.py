@@ -15,8 +15,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmarks.tables import NANO
 from repro.checkpoint import checkpoint as CK
+from repro.configs import NANO
 from repro.core import DSMConfig, adamw, dsm_init, global_sign_momentum_step
 from repro.core import workers as WK
 from repro.core.dsm import masked_worker_mean, worker_finite_mask
