@@ -3,8 +3,9 @@
   audit  — compile the dense / device-parallel / ZeRO-sharded outer steps
            and the bare local phase, parse their collectives, and check
            them against the budgets derived from repro.analysis.comm; also
-           print each program's relayout report (XLA remat clones and
-           bytes of re-laid-out state, hlo_audit.relayout_report).
+           print each program's relayout report (XLA remat clones,
+           kernel calls recomputed under remat and bytes of re-laid-out
+           state, hlo_audit.relayout_report).
            Forces a multi-device host (``--devices``, default 8) BEFORE
            jax is imported so the mesh is not degenerate.
   lint   — run the RPR0xx rules over files/directories.

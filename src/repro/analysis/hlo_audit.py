@@ -109,23 +109,33 @@ _INSTR_RE = re.compile(
 _LAYOUT_RE = re.compile(r"\{(?P<order>[\d,]*)")
 # ops that hand an entry parameter's buffer on unchanged
 _PASS_THROUGH = ("bitcast", "copy-start", "copy-done")
+_KERNEL_CALL = 'custom_call_target="tpu_custom_call"'
+_OP_NAME_RE = re.compile(r'op_name="(?P<op_name>[^"]*)"')
 
 
 def relayout_report(hlo_text: str) -> dict:
-    """Two counts of a compiled module that the program's math never asks
-    for:
+    """Three counts of a compiled module that the program's math never
+    asks for:
 
     * ``remat_clones``: instructions that XLA's memory rematerialization
       pass cloned (it names them ``<name>.remat<N>``) — values recomputed
       because the program did not fit in memory as scheduled;
+    * ``kernel_recomputes``: Pallas kernel calls (``tpu_custom_call``)
+      inside JAX's ``rematted_computation`` — a kernel's forward run again
+      in the backward because its checkpoint saved none of its outputs;
     * ``state_copy_bytes`` (and ``state_copies``): bytes of the entry
       computation's ``copy`` instructions that change the layout of an
       entry parameter, reached directly or through a bitcast or an async
       copy — state re-laid out at every call.
     """
     remat = set()
+    recomputes = 0
     entry, params, copies = False, {}, []
     for line in hlo_text.splitlines():
+        if _KERNEL_CALL in line:
+            op_name = _OP_NAME_RE.search(line)
+            if op_name and "rematted_computation" in op_name.group("op_name"):
+                recomputes += 1
         if line.startswith("ENTRY"):
             entry = True
             continue
@@ -152,7 +162,8 @@ def relayout_report(hlo_text: str) -> dict:
         return found.group("order") if found else ""
 
     moved = [shape for shape, src in copies if order(shape) != order(src)]
-    return {"remat_clones": len(remat), "state_copies": len(moved),
+    return {"remat_clones": len(remat), "kernel_recomputes": recomputes,
+            "state_copies": len(moved),
             "state_copy_bytes": sum(_shape_bytes(s) for s in moved)}
 
 

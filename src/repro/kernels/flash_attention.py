@@ -34,6 +34,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -43,6 +44,11 @@ LANES = 128
 MASK = -0.7 * float(jnp.finfo(jnp.float32).max)
 NT = (((1,), (1,)), ((), ()))   # a @ b.T
 TN = (((0,), (0,)), ((), ()))   # a.T @ b
+# Names of the forward's residuals that the backward reads and cannot get
+# from q, k and v alone: the output and the log-sum-exp.  A layer
+# checkpoint that saves them (``save_only_these_names(*RESIDUALS)``) keeps
+# the backward from running the forward kernel again.
+RESIDUALS = ("flash_attention_out", "flash_attention_lse")
 # Largest sequence x head-group lanes whose f32 dq accumulator the backward
 # keeps in VMEM: 4 MiB.
 MAX_SEQ_X_LANES = 1 << 20
@@ -260,6 +266,7 @@ def _attend(q, k, v, scale, hd):
 
 def _attend_fwd(q, k, v, scale, hd):
     o, lse = _fwd(q, k, v, scale=scale, hd=hd)
+    o, lse = (checkpoint_name(x, name) for x, name in zip((o, lse), RESIDUALS))
     return o, (q, k, v, o, lse)
 
 

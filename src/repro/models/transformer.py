@@ -23,6 +23,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from repro.kernels import flash_attention
 from repro.layout import STACK_KEY
 from repro.models import layers as L
 
@@ -30,6 +31,11 @@ PyTree = Any
 F32 = jnp.float32
 MOE_AUX_COEF = 0.01
 CE_CHUNK = 2048
+# What remat keeps of a layer: the flash kernel's output and log-sum-exp,
+# so the backward does not run the kernel's forward again.  Layers that do
+# not take the kernel name nothing and are recomputed whole.
+SAVE_KERNEL_RESIDUALS = jax.checkpoint_policies.save_only_these_names(
+    *flash_attention.RESIDUALS)
 
 
 def _parse_kind(kind: str) -> tuple[str, str]:
@@ -202,11 +208,12 @@ def _run_stack(stack, pattern, x, positions, cfg, enc_out=None, remat=True,
             aux = aux + a
         return (x, aux), None
 
+    policies = jax.checkpoint_policies
     if remat and remat_policy == "dots":
-        body_fn = jax.checkpoint(
-            body, policy=jax.checkpoint_policies.dots_with_no_batch_dims_saveable)
+        body_fn = jax.checkpoint(body, policy=policies.save_from_both_policies(
+            policies.dots_with_no_batch_dims_saveable, SAVE_KERNEL_RESIDUALS))
     elif remat:
-        body_fn = jax.checkpoint(body)
+        body_fn = jax.checkpoint(body, policy=SAVE_KERNEL_RESIDUALS)
     else:
         body_fn = body
     aux0 = jnp.zeros((), F32)

@@ -153,3 +153,101 @@ def test_mha_config_takes_the_kernel_on_tpu(on_tpu, arch, change, takes):
     with jax.set_mesh(mesh):   # for attn_seq_shard's sharding constraints
         assert ("pallas_call" in _loss_jaxpr(cfg, 128)) is takes
         assert "pallas_call" not in _loss_jaxpr(cfg, 96)    # S % 128 != 0
+
+
+# ---------------------------------------------------------------------------
+# Remat: the layer checkpoint keeps the kernel's output and log-sum-exp
+# ---------------------------------------------------------------------------
+
+def _pallas_calls(jaxpr) -> int:
+    return str(jaxpr).count("pallas_call[")
+
+
+@pytest.mark.parametrize("wrap,calls", [
+    (lambda layer: layer, 2),                    # forward, backward
+    (jax.checkpoint, 3),                         # and the forward again
+    (lambda layer: jax.checkpoint(layer, policy=T.SAVE_KERNEL_RESIDUALS), 2),
+], ids=["no_remat", "remat_saving_nothing", "remat_saving_the_residuals"])
+def test_layer_checkpoint_keeps_the_kernels_residuals(wrap, calls):
+    """A layer through the kernel and an output projection, traced (not
+    run: interpret mode cannot partially evaluate a checkpoint around the
+    kernel)."""
+    S, H, hd = 256, 2, 64
+
+    def layer(x, w):
+        q, k, v = ((x @ w[i]).reshape(1, S, H, hd) for i in range(3))
+        return FA.flash_attention(q, k, v).reshape(1, S, H * hd) @ w[3]
+
+    x = jax.ShapeDtypeStruct((1, S, H * hd), jnp.bfloat16)
+    w = jax.ShapeDtypeStruct((4, H * hd, H * hd), jnp.bfloat16)
+    grad = jax.grad(lambda x, w: wrap(layer)(x, w).astype(jnp.float32).sum(),
+                    argnums=1)
+    assert _pallas_calls(jax.make_jaxpr(grad)(x, w)) == calls
+
+
+@pytest.mark.parametrize("remat_policy", ["full", "dots"])
+def test_model_remat_runs_the_kernel_twice(on_tpu, remat_policy):
+    """Through ``loss_fn``'s layer scan, with either remat policy: the
+    forward and the fused backward, no third call."""
+    cfg = dataclasses.replace(load_arch("gpt2_small").SMOKE, head_dim=64)
+    params = jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0), cfg))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, 128), jnp.int32)}
+    grad = jax.grad(lambda p, b: T.loss_fn(p, b, cfg, remat=True,
+                                           remat_policy=remat_policy))
+    assert _pallas_calls(jax.make_jaxpr(grad)(params, batch)) == 2
+
+
+def _saved_by_remat(cfg, seq=128):
+    """Shapes and dtypes of what the gradient of ``loss_fn`` (remat on)
+    keeps from its forward for its backward."""
+    params = jax.eval_shape(lambda: T.init_params(jax.random.PRNGKey(0), cfg))
+    batch = {"tokens": jax.ShapeDtypeStruct((2, seq), jnp.int32)}
+    if cfg.family == "encdec":
+        batch["frames"] = jax.ShapeDtypeStruct((2, 16, cfg.d_model), cfg.act_dtype)
+
+    def residuals(p, b):
+        _, vjp = jax.vjp(lambda p: T.loss_fn(p, b, cfg, remat=True), p)
+        return jax.tree.leaves(vjp)
+
+    return sorted((r.shape, str(r.dtype))
+                  for r in jax.eval_shape(residuals, params, batch))
+
+
+def _with_plain_checkpoint(monkeypatch):
+    """``jax.checkpoint`` with no policy: remat that saves nothing."""
+    checkpoint = jax.checkpoint
+    monkeypatch.setattr(jax, "checkpoint",
+                        lambda fn, policy=None: checkpoint(fn))
+
+
+@pytest.mark.parametrize("arch,backend", [
+    ("gpt2_small", "cpu"),            # the einsum path
+    ("whisper_large_v3", "cpu"),      # and the encoder's full attention
+    ("gemma3_1b", "tpu"),             # sliding window, GQA
+    ("recurrentgemma_2b", "tpu"),     # RG-LRU, sliding window
+    ("granite_moe_3b_a800m", "tpu"),  # GQA, MoE
+])
+def test_remat_saves_nothing_where_the_kernel_is_not_taken(monkeypatch, arch,
+                                                           backend):
+    cfg = dataclasses.replace(load_arch(arch).SMOKE, head_dim=64)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    saved = _saved_by_remat(cfg)
+    _with_plain_checkpoint(monkeypatch)
+    assert saved == _saved_by_remat(cfg)
+
+
+def test_remat_saves_the_kernels_output_and_lse_per_layer(on_tpu,
+                                                          monkeypatch):
+    """Where the kernel is taken, remat keeps two more stacked values than
+    a checkpoint that saves nothing: each layer's output (B, S, H*hd) in
+    the activations' dtype and its log-sum-exp (B, H, 1, S) in f32."""
+    cfg = dataclasses.replace(load_arch("gpt2_small").SMOKE, head_dim=64)
+    saved = _saved_by_remat(cfg)
+    _with_plain_checkpoint(monkeypatch)
+    plain = _saved_by_remat(cfg)
+    for r in plain:
+        saved.remove(r)
+    n, S, H = cfg.n_layers, 128, cfg.n_heads
+    assert sorted(saved) == sorted([
+        ((n, 2, S, H * 64), str(jnp.dtype(cfg.act_dtype))),
+        ((n, 2, H, 1, S), "float32")])

@@ -6,7 +6,9 @@ tests compile the fused DSM kernel at GPT-2 small slab shapes and whole DSM
 outer steps at GPT-2 small and medium widths for one v5e chip, from shapes
 alone.  The whole steps also read ``hlo_audit.relayout_report``: with the
 worker axis second in layer-stacked state (repro.core.workers) the tau
-loop re-lays out no state, and XLA recomputes nothing for want of memory.
+loop re-lays out no state, XLA recomputes nothing for want of memory, and
+the layer checkpoint keeps the attention kernel's output and log-sum-exp,
+so no kernel call runs again under remat.
 
 The topology is described inside a module fixture, never at import: only
 one process at a time may load the TPU library, so describing it while the
@@ -147,15 +149,18 @@ def test_relayout_report_reads_the_compilers_copies(one_chip, worker_axis,
 
 
 def _kernel_path_in_attention(hlo, score_dims=r"[\d,]*1024,1024"):
-    """The fused attention kernel's calls keep the attention and remat
-    scopes, and no (S, S) score convolution (output dims ``score_dims``)
-    is left."""
+    """The fused attention kernel's calls keep the attention scope, the
+    layer checkpoint keeps the kernel's output and log-sum-exp so the
+    backward runs no forward call again, and no (S, S) score convolution
+    (output dims ``score_dims``) is left."""
+    from repro.analysis.hlo_audit import relayout_report
+
     calls = [line for line in hlo.splitlines()
              if 'custom_call_target="tpu_custom_call"' in line]
     names = [re.search(r'op_name="([^"]*)"', c).group(1) for c in calls]
-    # forward, remat forward, fused backward
-    assert len(names) == 3 and all("/attention/" in n for n in names), names
-    assert sum("rematted_computation" in n for n in names) == 1, names
+    # forward, fused backward
+    assert len(names) == 2 and all("/attention/" in n for n in names), names
+    assert relayout_report(hlo)["kernel_recomputes"] == 0, names
     scores = re.compile(rf"= \w+\[{score_dims}\]\S* convolution")
     assert not [line for line in hlo.splitlines() if scores.search(line)]
 
@@ -183,11 +188,12 @@ def test_gpt2_medium_dsm_step_with_flash_attention_fits_one_v5e(one_chip,
                                                                  monkeypatch):
     """All 24 layers of GPT-2 medium, W=2, with the fused attention kernel
     (eight groups of two heads of 64).  The bound is the compiler's own
-    peak of live bytes: 11.47 GB, where worker-first stacked state took
-    15.90 GB and made XLA recompute the logits, the MLP's w1 product and
-    six attention projections to fit.  With n_embd = S = 1024 every
-    projection is (W, B, S, 1024) too, so a score product is told by its
-    16-head axis."""
+    peak of live bytes: 11.89 GB with each layer's kernel output kept
+    across the layer checkpoint (11.47 GB without), where worker-first
+    stacked state took 15.90 GB and made XLA recompute the logits, the
+    MLP's w1 product and six attention projections to fit.  With n_embd =
+    S = 1024 every projection is (W, B, S, 1024) too, so a score product is
+    told by its 16-head axis."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     compiled, state = _compile_dsm_step(one_chip, "gpt2_medium", n_workers=2,
                                         n_layers=24)

@@ -272,16 +272,20 @@ ENTRY %main (a: f32[2,3], b: bf16[4,8], t: s32[]) -> f32[2,3] {
   %copy.3 = bf16[4,8]{0,1} copy(%copy-done.2)
   %copy.4 = s32[]{:T(128)S(6)} copy(%t)
   %fusion.5.remat = f32[2,3]{1,0} negate(%copy.1)
-  ROOT %copy.6 = f32[2,3]{1,0} copy(%fusion.5.remat)
+  %custom-call.7 = f32[2,3]{1,0} custom-call(%fusion.5.remat), custom_call_target="tpu_custom_call", metadata={op_name="jit(s)/checkpoint/rematted_computation/attention/flash_attention/pallas_call"}
+  %custom-call.8 = f32[2,3]{1,0} custom-call(%custom-call.7), custom_call_target="tpu_custom_call", metadata={op_name="jit(s)/transpose(jvp(attention))/flash_attention/pallas_call"}
+  ROOT %copy.6 = f32[2,3]{1,0} copy(%custom-call.8)
 }
 """
 
 
 def test_relayout_report_counts_clones_and_state_relayouts():
-    """Two remat clones; two copies that re-lay out an entry parameter,
-    one through an async copy (24 + 64 bytes); a copy into scalar memory
-    and a copy of a computed value are not state relayouts."""
+    """Two remat clones; one kernel call under JAX's remat, of two; two
+    copies that re-lay out an entry parameter, one through an async copy
+    (24 + 64 bytes); a copy into scalar memory and a copy of a computed
+    value are not state relayouts."""
     from repro.analysis.hlo_audit import relayout_report
 
-    assert relayout_report(_HLO) == {"remat_clones": 2, "state_copies": 2,
+    assert relayout_report(_HLO) == {"remat_clones": 2, "kernel_recomputes": 1,
+                                     "state_copies": 2,
                                      "state_copy_bytes": 88}
